@@ -21,6 +21,9 @@ from ..dsl import language as tl
 from ..lowering.pipeline import Knobs
 from .common import RecipeCtx, Recipe, two_phase_build
 
+# elements in one (8 sublane, 128 lane) tile of 32-bit values
+FLAT_TILE = 8 * 128
+
 
 def build_elementwise(task, shapes: Dict[str, Tuple[int, ...]], knobs: Knobs,
                       recipe: Recipe) -> A.Program:
@@ -55,8 +58,11 @@ def _build_elementwise_core(task, shapes: Dict[str, Tuple[int, ...]],
     numel = h.numel(first)
     n_cores = h.let("n_cores", tl.NUM_CORES,
                     rationale="fixed vector-core count")
+    # a rank-1 block must be a whole number of the chip's (8, 128) f32
+    # tiles, or Mosaic refuses it: round the per-core share up to that
+    per_core_share = tl.hcdiv(tl.hcdiv(numel, n_cores), FLAT_TILE) * FLAT_TILE
     tile_length = h.let(
-        "tile_length", tl.hmin(knobs.max_tile, tl.hcdiv(numel, n_cores)),
+        "tile_length", tl.hmin(knobs.max_tile, per_core_share),
         rationale=f"tile so {len(task.tensors)} live tiles fit the UB/VMEM "
                   f"budget; lane-aligned by Pass-4 padding")
     core_span = h.let("core_span", n_cores * tile_length,

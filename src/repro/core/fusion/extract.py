@@ -11,8 +11,8 @@ from the model itself and flow through the unchanged
 
 Normalization layers (in order):
 
-1. **Flattening** — ``pjit`` / ``custom_jvp_call`` / ``custom_vjp_call``
-   wrappers are inlined recursively (``jax.nn.silu`` arrives as a pjit
+1. **Flattening** — ``jit`` / ``custom_jvp_call`` / ``custom_vjp_call``
+   wrappers are inlined recursively (``jax.nn.silu`` arrives as a jit
    named ``silu``; ``scan``/``while``/``cond`` are *not* inlined — their
    sub-jaxprs stay opaque barriers).
 2. **Aliasing** — semantic no-ops vanish: ``convert_element_type``,
@@ -53,6 +53,7 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
+from jax.extend.core import Literal
 
 from .propose import OpGraph, OpNode, ProposeError, propose_chains
 
@@ -77,7 +78,7 @@ PRIM_MAP: Dict[str, str] = {
 # checkpointed functions arrive wrapped in it, and refusing to inline it
 # made every checkpointed backward graph an opaque barrier)
 INLINE_PRIMS = frozenset((
-    "pjit", "closed_call", "core_call", "named_call", "remat",
+    "jit", "closed_call", "core_call", "named_call", "remat",
     "remat2", "checkpoint", "custom_jvp_call", "custom_vjp_call",
     "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
 ))
@@ -215,9 +216,7 @@ class _Builder:
     # -- jaxpr walking -----------------------------------------------------
 
     def read(self, env, v):
-        import jax.core as jcore
-        lit = getattr(jcore, "Literal", None)
-        if lit is not None and isinstance(v, lit):
+        if isinstance(v, Literal):
             return self.val(getattr(v.aval, "shape", ()), "const",
                             const=v.val)
         return env[v]

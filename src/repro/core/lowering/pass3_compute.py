@@ -210,8 +210,9 @@ class BodyEmitter:
                     f"{codes[2]}).astype({dt})")
         if name == "iota":
             axis = op.attrs.get("axis", len(dst.shape) - 1)
-            return (f"{dst.name} = jax.lax.broadcasted_iota({dt}, "
-                    f"{self._shape_code(dst)}, {axis})")
+            # Mosaic lowers integer iotas only: build int32, then cast
+            return (f"{dst.name} = jax.lax.broadcasted_iota(jnp.int32, "
+                    f"{self._shape_code(dst)}, {axis}).astype({dt})")
         if name == "full":
             return (f"{dst.name} = jnp.full({self._shape_code(dst)}, "
                     f"{codes[0]}, {dt})")

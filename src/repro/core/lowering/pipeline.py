@@ -111,11 +111,12 @@ def transcompile(prog: A.Program, force_backend: Optional[str] = None,
 
     # Compile check: trace + (optionally) numerically verify vs DSL interp.
     # Only runs when check shapes are explicitly provided — interpret-mode
-    # execution at benchmark shapes would take minutes on CPU.
+    # execution at benchmark shapes would take minutes on CPU.  make()
+    # compiles for the chip on a TPU backend and interprets elsewhere.
     shapes = check_shapes
     if shapes:
         try:
-            fn = module.make(shapes, interpret=True)
+            fn = module.make(shapes)
         except Exception as e:  # noqa: BLE001
             raise TranscompileError(
                 "compile", f"make() failed: {type(e).__name__}: {e}", source)

@@ -180,6 +180,8 @@ class GenResult:
     oracle_ok: Optional[bool] = None
     cached: bool = False        # artifact served from the on-disk cache
     tune: Optional[Any] = None  # TuneResult when generate(tune=True)
+    # the check-shape build that Pass@1 ran (None when it was not run)
+    check_artifact: Optional[Artifact] = None
 
 
 def default_inputs(task: KernelTask, shapes: Dict[str, Tuple[int, ...]],
@@ -212,13 +214,13 @@ class NumericsCheck:
 def check_artifact_numerics(task: KernelTask, art_check: Artifact,
                             rtol: float = 3e-4, atol: float = 2e-5,
                             ) -> NumericsCheck:
-    """Run a check-shape artifact in the interpreter and compare against the
-    task reference.  Shared by the planner's Pass@1 verification and the
-    tuner's correctness gate."""
+    """Run a check-shape artifact (compiled on a TPU, interpreted
+    elsewhere) and compare against the task reference.  Shared by the
+    planner's Pass@1 verification and the tuner's correctness gate."""
     inputs = default_inputs(task, task.check_shapes)
     arrays = [inputs[tp.name] for tp in task.input_specs]
     try:
-        got = art_check.entry(*arrays, interpret=True)
+        got = art_check.entry(*arrays)
     except Exception as e:  # noqa: BLE001
         return NumericsCheck(False, float("nan"),
                              f"execution failed: {e}", exec_ok=False)
@@ -490,7 +492,8 @@ def generate(task: KernelTask, knobs: Optional[Knobs] = None,
                               exec_ok=False, error=chk.error,
                               verify_rtol=rtol, verify_atol=atol, axes=axes)
         return GenResult(task, art, False, False, error=chk.error,
-                         cached=cached_bench, tune=tune_result)
+                         cached=cached_bench, tune=tune_result,
+                         check_artifact=art_check)
     if cache_obj is not None:
         if cached_bench:
             # source already on disk: just persist the fresh verdict
@@ -510,4 +513,4 @@ def generate(task: KernelTask, knobs: Optional[Knobs] = None,
     return _emit_result(GenResult(
         task, art, True, chk.pass_ok, max_abs_err=chk.max_err,
         error=chk.error, oracle_ok=None, cached=cached_bench,
-        tune=tune_result))
+        tune=tune_result, check_artifact=art_check))

@@ -300,7 +300,7 @@ class GuardedResolver:
                    if np.issubdtype(np.asarray(a).dtype, np.floating)):
             return None
         try:
-            outs = result.artifact.entry(*arrays, interpret=True)
+            outs = result.artifact.entry(*arrays)
         except Exception:  # noqa: BLE001 — probe inconclusive, not a demotion
             return None
         outs = outs if isinstance(outs, (tuple, list)) else (outs,)
@@ -359,7 +359,7 @@ class GuardedResolver:
             art = result.artifact
             return Resolution(
                 task.name, fp, rung, result, tuple(events),
-                runner=lambda *arrays: art.entry(*arrays, interpret=True))
+                runner=lambda *arrays: art.entry(*arrays))
 
         # the floor: the task's own reference — pure JAX/numpy, cannot fail
         return Resolution(task.name, fp, "eager", None, tuple(events),

@@ -9,7 +9,7 @@ pipeline for every request.  Here:
   backend) plus registered program variants (alternative expert builders
   for the same op, e.g. pool2d row reuse).
 * :mod:`.tuner` — deterministic budgeted hill climb over that space,
-  ranked by the roofline cost model and gated on interpreter correctness.
+  ranked by the roofline cost model and gated on check-shape correctness.
 * :mod:`.cache` — content-addressed on-disk store of emitted kernel
   sources keyed by (task fingerprint, knobs, codegen version); a hit
   skips the whole lowering pipeline.

@@ -5,8 +5,9 @@ feedback loop turns a candidate into a compiling, verified kernel; the
 tuner decides *which* candidate to build, ranking points of
 :mod:`repro.core.tuning.space` by the deterministic roofline cost model
 (``repro.bench.model.fast_ratio``) and gating every candidate on
-correctness: the check-shape build must run under the Pallas interpreter
-and match the task reference within the planner's tolerances.
+correctness: the check-shape build must run (compiled on a TPU, under the
+Pallas interpreter elsewhere) and match the task reference within the
+planner's tolerances.
 
 Search: greedy hill climb with a hard evaluation budget.  Start from the
 default candidate, evaluate every single-axis neighbor (deterministic
@@ -143,7 +144,7 @@ def _evaluate(task, cand: Candidate, cache: Optional[ArtifactCache],
     except Exception as e:  # noqa: BLE001
         return Trial(cand, 0.0, False, f"cost model failed: {e}")
 
-    # Correctness gate: check-shape build runs in the interpreter and must
+    # Correctness gate: check-shape build must run and must
     # match the task reference (same bar the planner's Pass@1 applies).
     # A cached entry that already carries pass_ok=True was gated at the
     # same bar when stored — don't pay the check-shape build again.

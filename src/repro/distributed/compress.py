@@ -66,8 +66,7 @@ def make_compressed_allreduce(mesh: Mesh, grads_like):
     in_specs = jax.tree.map(lambda a: P(axes, *([None] * (a.ndim - 1))),
                             grads_like)
 
-    from ._compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(compressed_allreduce, axis_names=axes),
         mesh=mesh,
         in_specs=(in_specs, in_specs),

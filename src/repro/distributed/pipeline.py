@@ -56,7 +56,6 @@ def make_pipeline(mesh: Mesh, stage_fn: Callable, params_stacked,
             "stage")
         return outputs
 
-    from ._compat import shard_map
-    fn = shard_map(inner, mesh=mesh, in_specs=(pspec, P()),
-                   out_specs=P(), check_vma=False)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(pspec, P()),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)

@@ -48,15 +48,29 @@ def _chain_entry(Sq: int, Skv: int, D: int):
     return art.entry, float(dict(spec.attrs)["scale"])
 
 
-@functools.lru_cache(maxsize=8)
-def _causal_mask(Sq: int, Skv: int):
-    # additive causal mask, bottom-right aligned (decode-friendly): query i
-    # attends keys <= i + (Skv - Sq).  -3e38 is the chain's mask pad
-    # sentinel — finite, exp-underflows to exactly 0 like -inf, and
-    # survives the online-softmax rescale without NaNs.
-    qi = jnp.arange(Sq, dtype=jnp.int32)[:, None] + (Skv - Sq)
-    ki = jnp.arange(Skv, dtype=jnp.int32)[None, :]
-    return jnp.where(qi >= ki, 0.0, -3.0e38).astype(jnp.float32)
+# The chain lowers to the pipelined (BlockSpec) backend, the form Mosaic
+# compiles, only at lane-aligned row and key lengths; other lengths pad up.
+_ALIGN = 128
+
+
+def _round_up(n: int) -> int:
+    return -(-n // _ALIGN) * _ALIGN
+
+
+def _mask(Sq: int, Skv: int, causal: bool):
+    """Additive mask at the padded geometry.  Causal is bottom-right
+    aligned (decode-friendly): query i attends keys <= i + (Skv - Sq).
+    Padded keys take -3e38, the chain's mask pad sentinel: finite,
+    exp-underflows to exactly 0 like -inf, and survives the online-softmax
+    rescale without NaNs.  Padded query rows attend the real keys and are
+    sliced off."""
+    shape = (_round_up(Sq), _round_up(Skv))
+    qi = jax.lax.broadcasted_iota(jnp.int32, shape, 0) + (Skv - Sq)
+    ki = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    live = ki < Skv
+    if causal:
+        live = live & (qi >= ki)
+    return jnp.where(live, 0.0, -3.0e38).astype(jnp.float32)
 
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True,
@@ -73,20 +87,25 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
 
-    entry, baked = _chain_entry(Sq, Skv, D)
-    qf = jnp.asarray(q, jnp.float32) * (sm_scale / baked)
-    kf = jnp.asarray(k, jnp.float32)
-    vf = jnp.asarray(v, jnp.float32)
-    mask = _causal_mask(Sq, Skv) if causal \
-        else jnp.zeros((Sq, Skv), jnp.float32)
+    entry, baked = _chain_entry(_round_up(Sq), _round_up(Skv), D)
+
+    def pad_seq(x, s):
+        x = jnp.asarray(x, jnp.float32)
+        return jnp.pad(x, ((0, 0), (0, _round_up(s) - s), (0, 0), (0, 0)))
+
+    qf = pad_seq(q, Sq) * (sm_scale / baked)
+    kf = pad_seq(k, Skv)
+    vf = pad_seq(v, Skv)
+    mask = _mask(Sq, Skv, causal)
 
     batches = []
     for b in range(B):
         heads = [entry(qf[b, :, h, :], kf[b, :, h // group, :], mask,
                        vf[b, :, h // group, :])
                  for h in range(Hq)]
-        batches.append(jnp.stack(heads, axis=1))       # (Sq, Hq, D)
-    return jnp.stack(batches, axis=0).astype(q.dtype)  # (B, Sq, Hq, D)
+        batches.append(jnp.stack(heads, axis=1))       # (Sqp, Hq, D)
+    out = jnp.stack(batches, axis=0)[:, :Sq]           # (B, Sq, Hq, D)
+    return out.astype(q.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

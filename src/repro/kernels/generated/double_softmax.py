@@ -134,7 +134,7 @@ def make(shapes, interpret=None):
                 f0_yt = (f0_xt - f0_row_max)
                 f0_yt = jnp.exp(f0_yt)
                 f0_yt = (f0_yt / f0_row_den)
-                f0_padidx = jax.lax.broadcasted_iota(jnp.float32, (tile_length,), 0)
+                f0_padidx = jax.lax.broadcasted_iota(jnp.int32, (tile_length,), 0).astype(jnp.float32)
                 f0_padidx = (f0_padidx + f0_t2 * tile_length)
                 f0_padmsk = (f0_padidx < 786432.0).astype(jnp.float32)
                 f0_padnu = jnp.full((tile_length,), -3e+38, jnp.float32)

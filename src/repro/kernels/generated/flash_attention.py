@@ -165,7 +165,7 @@ def make(shapes, interpret=None):
                 f3_yt = (f3_xt - f3_row_max)
                 f3_yt = jnp.exp(f3_yt)
                 f3_yt = (f3_yt / f3_row_den)
-                f3_padidx = jax.lax.broadcasted_iota(jnp.float32, (tile_length,), 0)
+                f3_padidx = jax.lax.broadcasted_iota(jnp.int32, (tile_length,), 0).astype(jnp.float32)
                 f3_padidx = (f3_padidx + f3_t2 * tile_length)
                 f3_padmsk = (f3_padidx < 786432.0).astype(jnp.float32)
                 f3_padnu = jnp.full((tile_length,), 0.0, jnp.float32)

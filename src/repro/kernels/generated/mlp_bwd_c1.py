@@ -117,7 +117,7 @@ def make(shapes, interpret=None):
         # ---- compute ----
         f0_y1 = (f0_input_t * f0_x1_t)
         f1_y1 = (f1_x2_t * f0_y1)
-        f2_y1 = (f1_y1 + f2_x3_t)
+        f2_y1 = (f2_x3_t + f1_y1)
         # ---- copyout ----
         _o_output[...] = f2_y1
 

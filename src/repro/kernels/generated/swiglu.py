@@ -53,7 +53,7 @@ def _plan(shapes):
     """Host function: core partitioning + tiling strategy (pass 1)."""
     gate_numel = shapes['gate'][0]
     n_cores = 32  # fixed vector-core count
-    tile_length = min(4096, -(-(134217728) // (32)))  # tile so 3 live tiles fit the UB/VMEM budget; lane-aligned by Pass-4 padding
+    tile_length = min(4096, (-(-(-(-(134217728) // (32))) // (1024)) * 1024))  # tile so 3 live tiles fit the UB/VMEM budget; lane-aligned by Pass-4 padding
     core_span = 131072  # GM padded to a multiple of this (pass 4)
     padded_numel = (-(-(134217728) // (131072)) * 131072)
     per_core = 4194304

@@ -9,13 +9,17 @@ from __future__ import annotations
 import jax
 
 
+def make_mesh(shape, axes, devices=None):
+    """A mesh whose axes leave sharding propagation to XLA.  The sharding
+    rules are GSPMD annotations, so the axes are ``Auto`` (``jax.make_mesh``
+    defaults to ``Explicit`` sharding types)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         (jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """(16, 16) = 256 chips/pod single-pod, or (2, 16, 16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(shape, axes):
-    """Small mesh over however many (host) devices are present — tests."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

@@ -1,7 +1,12 @@
 """Serving launcher.
 
     PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
-        --requests 4
+        --requests 4 [--no-smoke --max-len 2048 --prompt-len 64 1024]
+
+``--smoke`` (the default) serves the architecture's reduced config;
+``--no-smoke`` serves it at its published widths.  Prompt lengths are
+uniform in ``--prompt-len MIN MAX``.  The process
+exits 1 unless every request completed and no fast-path resolution failed.
 
 Single-host slot engine on the container; the decode step is the same unit
 the dry-run lowers against the production mesh (launch/steps.py).
@@ -19,6 +24,7 @@ bucket.  Fleet warm-up options:
   instead of warming from scratch (the other-fleet-member side).
 """
 import argparse
+import sys
 
 import jax
 import numpy as np
@@ -27,11 +33,18 @@ from ..configs import get_config
 from ..models import transformer as T
 from ..serving import (Request, ServeEngine, kv_bucket_ladder,
                        warm_from_manifest, warm_kernel_cache)
+from .compile_cache import enable_compile_cache
 
 
-def main():
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced config (default) or published widths")
+    ap.add_argument("--prompt-len", type=int, nargs=2, default=(8, 8),
+                    metavar=("MIN", "MAX"),
+                    help="prompt lengths, uniform in [MIN, MAX]")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--slots", type=int, default=2)
     ap.add_argument("--max-new", type=int, default=8)
@@ -58,9 +71,10 @@ def main():
     ap.add_argument("--warm-manifest", default=None,
                     help="replay a published warm-up manifest into the "
                          "cache instead of warming from scratch")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch, smoke=True)
+    enable_compile_cache()
+    cfg = get_config(args.arch, smoke=args.smoke)
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     cache = args.cache if args.cache else None
     if args.warm_manifest:
@@ -87,7 +101,9 @@ def main():
                 kv_dtype=args.kv_dtype)
             print(f"published manifest -> {args.publish_manifest}")
     rng = np.random.RandomState(0)
-    reqs = [Request(uid=i, prompt=rng.randint(0, cfg.vocab, 8)
+    lo, hi = args.prompt_len
+    reqs = [Request(uid=i, prompt=rng.randint(0, cfg.vocab,
+                                              rng.randint(lo, hi + 1))
                     .astype(np.int32), max_new_tokens=args.max_new)
             for i in range(args.requests)]
     engine.run(reqs, deadline_s=args.deadline_s)
@@ -105,7 +121,8 @@ def main():
               f"kv_dtype={engine.fastpath.kv_dtype} "
               f"hits={engine.fastpath.hits} "
               f"misses={engine.fastpath.misses}")
+    return 0 if rep.ok and rep.fastpath_errors == 0 else 1
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

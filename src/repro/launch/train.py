@@ -1,37 +1,30 @@
 """Sharded training launcher (production entry point).
 
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
-        --smoke --steps 20 --mesh-shape 1,1
+        --steps 20 --mesh-shape 1,1 [--no-smoke]
 
-On real hardware: jax.distributed.initialize() + the production mesh; on
-the container: a (1,1) host mesh with the same code path.  Includes the
-fault-tolerance loop: checkpoint-every-k, auto-resume, straggler/deadline
-monitor, and XLA latency-hiding flags for compute/comm overlap.
+``--smoke`` (the default) trains the architecture's reduced config;
+``--no-smoke`` trains it at its published widths.  The step is
+:func:`make_sharded_train_step` over a ``(data, model)`` mesh; on the
+container that mesh is (1, 1).  Includes the fault-tolerance loop:
+checkpoint-every-k, auto-resume and a straggler/deadline monitor.
 """
+import argparse
 import os
+import tempfile
+import time
 
-# compute/comm overlap: enable XLA's latency-hiding scheduler (no-op on CPU)
-os.environ.setdefault("LIBTPU_INIT_ARGS", "")
-_OVERLAP_FLAGS = (
-    " --xla_tpu_enable_async_collective_fusion=true"
-    " --xla_tpu_overlap_compute_collective_tc=true"
-    " --xla_tpu_enable_async_collective_fusion_fuse_all_gather=true"
-)
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-import argparse      # noqa: E402
-import time          # noqa: E402
-
-import jax           # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np   # noqa: E402
-
-from ..checkpoint import CheckpointManager               # noqa: E402
-from ..configs import get_config                         # noqa: E402
-from ..data import DataConfig, SyntheticLM               # noqa: E402
-from ..distributed import sharding as S                  # noqa: E402
-from ..models import transformer as T                    # noqa: E402
-from ..training import optimizer as opt                  # noqa: E402
-from ..training.train import make_train_step             # noqa: E402
+from ..checkpoint import CheckpointManager
+from ..configs import get_config
+from ..data import DataConfig, SyntheticLM
+from ..training import optimizer as opt
+from ..training.train import init_sharded, make_sharded_train_step
+from .compile_cache import enable_compile_cache
+from .mesh import make_mesh
 
 
 class StragglerMonitor:
@@ -56,10 +49,12 @@ class StragglerMonitor:
         return slow
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced config (default) or published widths")
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
@@ -71,43 +66,46 @@ def main():
                     help="run the mHC backward through the extracted "
                          "mhc_stream_bwd fusion chain (DESIGN.md §16); "
                          "requires --hyper-connections > 0 to matter")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_launch_train")
+    ap.add_argument("--ckpt-dir", default=os.path.join(
+        tempfile.gettempdir(), "repro_launch_train"))
     ap.add_argument("--ckpt-every", type=int, default=10)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def config_from_args(args):
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.hyper_connections:
         cfg = cfg.scaled(hyper_connections=args.hyper_connections)
+    return cfg
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg = config_from_args(args)
     shape = tuple(int(x) for x in args.mesh_shape.split(","))
     axes = ("data", "model")[: len(shape)] if len(shape) <= 2 \
         else ("pod", "data", "model")
-    mesh = jax.make_mesh(shape, axes)
-    ocfg = opt.AdamWConfig(lr=1e-3, warmup_steps=10, total_steps=args.steps)
+    mesh = make_mesh(shape, axes)
     data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=args.seq_len,
                                   global_batch=args.batch))
     mgr = CheckpointManager(args.ckpt_dir, keep=2)
     mon = StragglerMonitor()
 
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
-    state = opt.init(params)
+    batch0 = {k: jnp.asarray(v) for k, v in data.batch(0).items()}
+    ocfg = opt.AdamWConfig(lr=1e-3, warmup_steps=10, total_steps=args.steps)
+    step_fn, (pshard, oshard, bshard) = make_sharded_train_step(
+        cfg, ocfg, mesh, batch0, args.grad_accum,
+        fused_backward=args.fused_mhc_bwd)
+    params, state = init_sharded(cfg, pshard, oshard)
     start = 0
     if mgr.latest_step() is not None:
         restored, meta = mgr.restore(mgr.latest_step(),
                                      {"params": params, "opt": state})
-        params, state = restored["params"], restored["opt"]
+        params = jax.device_put(restored["params"], pshard)
+        state = jax.device_put(restored["opt"], oshard)
         start = meta["data_step"]
         print(f"[resume] from step {start}")
-
-    pshard = S.param_shardings(mesh, params)
-    oshard = S.opt_state_shardings(mesh, state, params)
-    batch0 = {k: jnp.asarray(v) for k, v in data.batch(0).items()}
-    bshard = S.batch_shardings(mesh, batch0)
-    params = jax.device_put(params, pshard)
-    state = jax.device_put(state, oshard)
-    step_fn = jax.jit(make_train_step(cfg, ocfg, args.grad_accum,
-                                      fused_backward=args.fused_mhc_bwd),
-                      in_shardings=(pshard, oshard, bshard),
-                      donate_argnums=(0, 1))
 
     for step in range(start, args.steps):
         t0 = time.time()

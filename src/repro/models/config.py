@@ -79,6 +79,9 @@ class ArchConfig:
     sinkhorn_iters: int = 5
 
     dtype: str = "bfloat16"
+    # attention: "auto" runs the generated flash chain on a TPU and XLA
+    # elsewhere; "xla" runs the reference on every backend
+    attn_impl: str = "auto"
     remat: str = "full"                      # "none" | "dots" | "full"
     # decode/serving: unroll the layer loop (python loop, static parameter
     # slices, per-layer cache arrays).  Scanning over a layer-stacked KV

@@ -130,7 +130,8 @@ def apply_attention(p, x, cfg: ArchConfig, *, positions=None, cache=None):
     k = apply_rope(k, cos, sin)
 
     if cache is None:
-        out = fa_ops.attention(q, k, v, causal=cfg.causal)
+        out = fa_ops.attention(q, k, v, causal=cfg.causal,
+                               impl=cfg.attn_impl)
         new_cache = None
     else:
         # decode: S == 1; write k/v at position `length`, attend over cache

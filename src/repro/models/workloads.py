@@ -45,8 +45,12 @@ class Workload:
 
 # a minimal rmsnorm config for apply_norm (structure-only: sizes are the
 # trace shapes below, never this config's)
+# attention traces as the XLA reference on every backend: on a TPU, "auto"
+# would trace the generated flash chain, which needs the very chains this
+# extraction derives
 _CFG = ArchConfig(name="trace", n_layers=1, d_model=64, n_heads=4,
-                  n_kv_heads=2, d_ff=128, vocab=64, norm="rmsnorm")
+                  n_kv_heads=2, d_ff=128, vocab=64, norm="rmsnorm",
+                  attn_impl="xla")
 # the same config in its layernorm variant (post-LN blocks)
 _LN_CFG = ArchConfig(name="trace_ln", n_layers=1, d_model=64, n_heads=4,
                      n_kv_heads=2, d_ff=128, vocab=64, norm="layernorm")

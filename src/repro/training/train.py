@@ -79,16 +79,21 @@ def make_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig,
 
 def make_sharded_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, mesh,
                             batch_specs: Dict[str, Any],
-                            grad_accum: int = 1, donate: bool = True):
-    """jit the train step with explicit in/out shardings for `mesh`."""
+                            grad_accum: int = 1,
+                            fused_backward: bool = False):
+    """jit the train step with explicit in/out shardings for `mesh`:
+    params and state come out as they went in, so the next step takes them
+    as they are, and both are donated.  Returns (step, (param shardings,
+    state shardings, batch shardings))."""
     from ..distributed import sharding as S
-    step = make_train_step(cfg, ocfg, grad_accum)
-
-    def abstract_params():
-        return jax.eval_shape(lambda k: T.init_params(k, cfg),
-                              jax.random.PRNGKey(0))
-
-    aparams = abstract_params()
+    if mesh.size > 1 and cfg.attn_impl == "auto":
+        # GSPMD cannot partition a Mosaic kernel: until the flash chain is
+        # wrapped in shard_map, a sharded step runs XLA attention
+        cfg = cfg.scaled(attn_impl="xla")
+    step = make_train_step(cfg, ocfg, grad_accum,
+                           fused_backward=fused_backward)
+    aparams = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0),
+                                                   cfg))
     pshard = S.param_shardings(mesh, aparams)
     astate = jax.eval_shape(opt.init, aparams)
     oshard = S.opt_state_shardings(mesh, astate, aparams)
@@ -100,6 +105,15 @@ def make_sharded_train_step(cfg: ArchConfig, ocfg: opt.AdamWConfig, mesh,
         step,
         in_shardings=(pshard, oshard, bshard),
         out_shardings=(pshard, oshard, metrics_shard),
-        donate_argnums=(0, 1) if donate else (),
+        donate_argnums=(0, 1),
     )
     return jitted, (pshard, oshard, bshard)
+
+
+def init_sharded(cfg: ArchConfig, pshard, oshard, seed: int = 0):
+    """Params (from ``seed``) and AdamW state, created directly in their
+    shardings: at published widths the optimizer state does not fit one
+    chip."""
+    params = jax.jit(lambda: T.init_params(jax.random.PRNGKey(seed), cfg),
+                     out_shardings=pshard)()
+    return params, jax.jit(opt.init, out_shardings=oshard)(params)

@@ -179,6 +179,36 @@ def test_composite_recognition(fn, ops):
     assert [st.op for st in spec.stages] == ops
 
 
+def _jaxpr_prims(fn, *shapes):
+    closed = jax.make_jaxpr(fn)(*[jnp.zeros(s, jnp.float32) for s in shapes])
+    return [e.primitive.name for e in closed.jaxpr.eqns]
+
+
+@pytest.mark.parametrize("fn,ops", [
+    (lambda x, b: jax.nn.silu(x + b), ["add", "silu"]),
+    (lambda x, b: jax.jit(jax.nn.softmax)(x + b), ["add", "softmax"]),
+])
+def test_jit_wrapped_composites_are_inlined(fn, ops):
+    """``jax.nn.silu`` arrives wrapped in a ``jit`` call primitive, as does
+    any jitted helper; extraction must look through it, not keep it as an
+    opaque barrier."""
+    assert "jit" in _jaxpr_prims(fn, (4, 64), (64,))
+    spec = _single_chain(fn, (("input", (4, 64)), ("bias", (64,))))
+    assert [st.op for st in spec.stages] == ops
+
+
+def test_literals_extract_as_constants():
+    """``max(h, 0.0)`` carries its 0.0 as a jaxpr Literal: read as a
+    constant it completes the relu composite."""
+    from jax.extend.core import Literal
+    fn = lambda x, b: jnp.maximum(x + b, 0.0)  # noqa: E731
+    closed = jax.make_jaxpr(fn)(jnp.zeros((4, 64)), jnp.zeros(64))
+    assert any(isinstance(v, Literal)
+               for e in closed.jaxpr.eqns for v in e.invars)
+    spec = _single_chain(fn, (("input", (4, 64)), ("bias", (64,))))
+    assert [st.op for st in spec.stages] == ["add", "relu"]
+
+
 def test_rank3_model_tensors_canonicalize_to_rank2_chains():
     """(B, S, d) activations flatten to row tensors; trailing-broadcast
     weights stay rank-1 vectors."""

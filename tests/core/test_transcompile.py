@@ -33,8 +33,9 @@ def build_elementwise_chain(shapes, ops, pad=False):
                 with tl.copyin():
                     tl.load("input", off, buf)
                 with tl.compute():
-                    for opname in ops:
-                        getattr(tl, opname)(buf, buf)
+                    for op in ops:
+                        (getattr(tl, op) if isinstance(op, str) else op)(
+                            buf, buf)
                 with tl.copyout():
                     tl.store("output", off, buf)
         return P.build()
@@ -90,6 +91,24 @@ def test_lowered_matches_interpreter_oracle():
     got = np.asarray(art.module.make(shapes, interpret=True)(x))
     np.testing.assert_allclose(got.reshape(-1), want.reshape(-1)[:numel],
                                rtol=1e-5, atol=1e-6)
+
+
+def test_iota_is_int32_before_its_cast():
+    """Mosaic lowers integer iotas only: an f32 index tile is emitted as an
+    int32 iota cast to the buffer dtype, and still counts 0..tile-1."""
+    numel = 2048
+    shapes = {"input": (numel,), "output": (numel,)}
+    prog = build_elementwise_chain(
+        shapes, [lambda dst, src: tl.iota(dst, axis=0)])
+    art = transcompile(prog)
+    iotas = [ln for ln in art.source.splitlines() if "broadcasted_iota" in ln]
+    assert iotas and all(
+        "broadcasted_iota(jnp.int32" in ln and ".astype(jnp.float32)" in ln
+        for ln in iotas), iotas
+    tile = prog.meta["plan"]["tile_length"]
+    out = np.asarray(art.module.make(shapes, interpret=True)(
+        np.zeros(numel, np.float32)))
+    np.testing.assert_array_equal(out, np.arange(numel) % tile)
 
 
 def test_generated_source_is_readable_artifact():

@@ -39,8 +39,9 @@ def test_sharded_train_step_and_elastic_remesh(tmp_path):
 
         cfg = get_config('internlm2-1.8b', smoke=True)
         ocfg = opt.AdamWConfig(lr=1e-3, warmup_steps=0)
-        mesh42 = jax.make_mesh((4, 2), ('data', 'model'))
-        mesh24 = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh42 = make_mesh((4, 2), ('data', 'model'))
+        mesh24 = make_mesh((2, 4), ('data', 'model'))
 
         params = T.init_params(jax.random.PRNGKey(0), cfg)
         state = opt.init(params)

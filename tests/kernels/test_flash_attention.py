@@ -29,6 +29,8 @@ SHAPES = [
     (2, 256, 256, 4, 2, 64),      # GQA 2:1
     (1, 128, 512, 8, 1, 32),      # MQA, cross longer KV
     (2, 384, 384, 4, 4, 128),     # non-pow2 seq
+    (1, 100, 100, 2, 1, 32),      # unaligned: rows and keys pad to 128
+    (1, 64, 200, 2, 2, 32),       # unaligned cross-length KV
 ]
 
 
@@ -51,6 +53,17 @@ def test_flash_bit_exact_at_resident_geometry():
     out = flash_attention_fwd(q, k, v, causal=True)
     ref = mha_reference(q, k, v, causal=True)
     assert np.array_equal(np.asarray(out), np.asarray(ref))
+
+
+def test_flash_traces_again_at_one_geometry():
+    """Two jitted programs at the same (Sq, Skv): nothing cached by the
+    first trace may leak into the second (serving traces prefill once per
+    prompt length, then again for every new program at that length)."""
+    q, k, v = _mk(1, 100, 100, 2, 1, 32, jnp.float32)
+    a = jax.jit(lambda q: flash_attention_fwd(q, k, v))(q)
+    b = jax.jit(lambda q: flash_attention_fwd(q, k, v) * 2.0)(q)
+    np.testing.assert_allclose(np.asarray(b), 2.0 * np.asarray(a),
+                               rtol=1e-6, atol=1e-6)
 
 
 def test_flash_explicit_sm_scale_folded_into_q():

@@ -28,3 +28,22 @@ def test_train_launcher_runs_and_resumes(tmp_path):
     out2 = _train(ckpt, steps=12)
     assert "[resume] from step 7" in out2, out2
     assert "done" in out2
+
+
+def test_train_no_smoke_resolves_to_published_config():
+    from repro.configs import get_config
+    from repro.launch import train
+    assert train.config_from_args(train.parse_args([])) == \
+        get_config("internlm2-1.8b", smoke=True)
+    assert train.config_from_args(train.parse_args(["--no-smoke"])) == \
+        get_config("internlm2-1.8b")
+
+
+def test_serve_exit_code_reflects_failed_requests():
+    from repro.core.resilience.faults import FaultPlan, FaultSpec, inject
+    from repro.launch import serve
+    argv = ["--requests", "2", "--max-new", "2", "--prompt-len", "4", "6"]
+    assert serve.main(argv) == 0
+    # every prefill crashes: both requests fail after their retries
+    with inject(FaultPlan([FaultSpec("serve.admit", times=None)])):
+        assert serve.main(argv) == 1
