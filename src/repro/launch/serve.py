@@ -22,8 +22,14 @@ bucket.  Fleet warm-up options:
   JSON manifest;
 * ``--warm-manifest PATH`` replays a published manifest into the cache
   instead of warming from scratch (the other-fleet-member side).
+
+``--profile DIR`` writes a profiler trace of the serving run into DIR
+(``DIR/plugins/profile/<time>/*.xplane.pb``): the engine's ``serve.*``
+spans, the ``jit_serve_prefill``/``jit_serve_decode`` programs and, on a
+TPU, each device operation with its layer scope.  Off by default.
 """
 import argparse
+import contextlib
 import sys
 
 import jax
@@ -71,6 +77,8 @@ def main(argv=None) -> int:
     ap.add_argument("--warm-manifest", default=None,
                     help="replay a published warm-up manifest into the "
                          "cache instead of warming from scratch")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="write a profiler trace of the serving run here")
     args = ap.parse_args(argv)
 
     enable_compile_cache()
@@ -106,7 +114,9 @@ def main(argv=None) -> int:
                                               rng.randint(lo, hi + 1))
                     .astype(np.int32), max_new_tokens=args.max_new)
             for i in range(args.requests)]
-    engine.run(reqs, deadline_s=args.deadline_s)
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        engine.run(reqs, deadline_s=args.deadline_s)
     for r in reqs:
         tag = f"  [FAILED: {r.error}]" if r.error else ""
         print(f"req {r.uid}: {r.generated}{tag}")

@@ -70,34 +70,73 @@ def _apply_block(p, spec: LayerSpec, x, cfg: ArchConfig, positions, cache):
     raise ValueError(spec.block)
 
 
+# Named scopes put each op's layer into its HLO metadata (``op_name``), so a
+# profiler trace splits a step's device time by layer.  They change no jaxpr.
+
+def _block_scope(spec: LayerSpec) -> str:
+    return "attention" if spec.block == "attn" else spec.block
+
+
+def _norm(p, x, cfg: ArchConfig):
+    with jax.named_scope("norm"):
+        return L.apply_norm(p, x, cfg)
+
+
+def _apply_ffn(p, spec: LayerSpec, h, cfg: ArchConfig):
+    if spec.ffn == "moe":
+        with jax.named_scope("moe"):
+            return L.apply_moe(p, h, cfg)
+    with jax.named_scope("mlp"):
+        return L.apply_mlp(p, h, spec.ffn)
+
+
+def _mhc_pre(p, streams):
+    with jax.named_scope("mhc"):
+        return L.mhc_pre(p, streams)
+
+
+def _mhc_post(p, streams, out, cfg: ArchConfig):
+    with jax.named_scope("mhc"):
+        return L.mhc_post(p, streams, out, cfg)
+
+
+def _lm_head(params, cfg: ArchConfig, state):
+    """The final norm and the output projection."""
+    with jax.named_scope("lm_head"):
+        h = L.apply_norm(params["final_norm"], state, cfg)
+        if cfg.encoder_only:
+            return h @ params["head"]
+        if cfg.tie_embeddings:
+            return h @ params["embed"].T
+        return h @ params["lm_head"]
+
+
 def _apply_layer(p, spec: LayerSpec, state, cfg: ArchConfig, positions,
                  cache):
     """state: x (B,S,d) or streams (n,B,S,d) when hyper-connections on."""
     if cfg.hyper_connections:
         streams = state
-        inp = L.mhc_pre(p["mhc_block"], streams)
-        out, new_cache = _apply_block(p["block"], spec,
-                                      L.apply_norm(p["norm1"], inp, cfg),
-                                      cfg, positions, cache)
-        streams = L.mhc_post(p["mhc_block"], streams, out, cfg)
+        inp = _mhc_pre(p["mhc_block"], streams)
+        h = _norm(p["norm1"], inp, cfg)
+        with jax.named_scope(_block_scope(spec)):
+            out, new_cache = _apply_block(p["block"], spec, h, cfg,
+                                          positions, cache)
+        streams = _mhc_post(p["mhc_block"], streams, out, cfg)
         if spec.ffn != "none":
-            inp = L.mhc_pre(p["mhc_ffn"], streams)
-            h = L.apply_norm(p["norm2"], inp, cfg)
-            out = (L.apply_moe(p["ffn"], h, cfg) if spec.ffn == "moe"
-                   else L.apply_mlp(p["ffn"], h, spec.ffn))
-            streams = L.mhc_post(p["mhc_ffn"], streams, out, cfg)
+            inp = _mhc_pre(p["mhc_ffn"], streams)
+            out = _apply_ffn(p["ffn"], spec, _norm(p["norm2"], inp, cfg),
+                             cfg)
+            streams = _mhc_post(p["mhc_ffn"], streams, out, cfg)
         return streams, new_cache
 
     x = state
-    out, new_cache = _apply_block(p["block"], spec,
-                                  L.apply_norm(p["norm1"], x, cfg),
-                                  cfg, positions, cache)
+    h = _norm(p["norm1"], x, cfg)
+    with jax.named_scope(_block_scope(spec)):
+        out, new_cache = _apply_block(p["block"], spec, h, cfg, positions,
+                                      cache)
     x = x + out
     if spec.ffn != "none":
-        h = L.apply_norm(p["norm2"], x, cfg)
-        out = (L.apply_moe(p["ffn"], h, cfg) if spec.ffn == "moe"
-               else L.apply_mlp(p["ffn"], h, spec.ffn))
-        x = x + out
+        x = x + _apply_ffn(p["ffn"], spec, _norm(p["norm2"], x, cfg), cfg)
     return x, new_cache
 
 
@@ -141,11 +180,12 @@ def _embed_inputs(params, cfg: ArchConfig, batch):
     frame/patch embeddings arrive in the batch (DESIGN.md §4)."""
     if cfg.frontend == "audio":
         return batch["frames"].astype(jnp.dtype(cfg.dtype))
-    tok = params["embed"][batch["tokens"]]
-    if cfg.frontend == "patch":
-        return jnp.concatenate(
-            [batch["patch_embeds"].astype(tok.dtype), tok], axis=1)
-    return tok
+    with jax.named_scope("embed"):
+        tok = params["embed"][batch["tokens"]]
+        if cfg.frontend == "patch":
+            return jnp.concatenate(
+                [batch["patch_embeds"].astype(tok.dtype), tok], axis=1)
+        return tok
 
 
 def _body_scan(params, cfg: ArchConfig, state, positions, caches=None):
@@ -210,13 +250,7 @@ def forward(params, cfg: ArchConfig, batch, caches=None):
     state, new_body = _body_scan(params, cfg, state, positions, body_caches)
     if cfg.hyper_connections:
         state = state.sum(0)
-    h = L.apply_norm(params["final_norm"], state, cfg)
-    if cfg.encoder_only:
-        logits = h @ params["head"]
-    elif cfg.tie_embeddings:
-        logits = h @ params["embed"].T
-    else:
-        logits = h @ params["lm_head"]
+    logits = _lm_head(params, cfg, state)
     new_caches = None
     if caches is not None:
         new_caches = {"prelude": new_prelude, "body": new_body}
@@ -295,7 +329,8 @@ def _unrolled_layer_params(params, cfg: ArchConfig, rep: int):
 
 def decode_step(params, cfg: ArchConfig, tokens, caches):
     """tokens: (B, 1) int32 -> (logits (B, 1, V), new caches)."""
-    x = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
     # positions for rope come from per-layer cache lengths; use the first
     # attention cache's length (all layers advance in lockstep)
     pos = _first_length(caches, cfg)
@@ -330,11 +365,7 @@ def decode_step(params, cfg: ArchConfig, tokens, caches):
         body_key, body_val = "body", new_body
     if cfg.hyper_connections:
         state = state.sum(0)
-    h = L.apply_norm(params["final_norm"], state, cfg)
-    if cfg.tie_embeddings:
-        logits = h @ params["embed"].T
-    else:
-        logits = h @ params.get("lm_head", params.get("head"))
+    logits = _lm_head(params, cfg, state)
     return logits, {"prelude": new_prelude, body_key: body_val}
 
 
@@ -373,30 +404,30 @@ def _prefill_forward(params, cfg, batch, caches):
         # run parallel block; then write sequence K/V (attn) or final state
         # (recurrent blocks) into the cache.
         if cfg.hyper_connections:
-            inp = L.mhc_pre(p["mhc_block"], state)
+            inp = _mhc_pre(p["mhc_block"], state)
         else:
             inp = state
-        h = L.apply_norm(p["norm1"], inp, cfg)
-        if spec.block == "attn":
-            new_cache = _fill_attn_cache(p["block"], h, cfg, cache, positions)
-        else:
-            new_cache = _fill_recurrent_cache(p["block"], spec, h, cfg, cache)
-        out, _ = _apply_block(p["block"], spec, h, cfg, positions, None)
+        h = _norm(p["norm1"], inp, cfg)
+        with jax.named_scope(_block_scope(spec)):
+            if spec.block == "attn":
+                new_cache = _fill_attn_cache(p["block"], h, cfg, cache,
+                                             positions)
+            else:
+                new_cache = _fill_recurrent_cache(p["block"], spec, h, cfg,
+                                                  cache)
+            out, _ = _apply_block(p["block"], spec, h, cfg, positions, None)
         if cfg.hyper_connections:
-            state = L.mhc_post(p["mhc_block"], state, out, cfg)
+            state = _mhc_post(p["mhc_block"], state, out, cfg)
             if spec.ffn != "none":
-                inp2 = L.mhc_pre(p["mhc_ffn"], state)
-                h2 = L.apply_norm(p["norm2"], inp2, cfg)
-                out2 = (L.apply_moe(p["ffn"], h2, cfg) if spec.ffn == "moe"
-                        else L.apply_mlp(p["ffn"], h2, spec.ffn))
-                state = L.mhc_post(p["mhc_ffn"], state, out2, cfg)
+                inp2 = _mhc_pre(p["mhc_ffn"], state)
+                out2 = _apply_ffn(p["ffn"], spec,
+                                  _norm(p["norm2"], inp2, cfg), cfg)
+                state = _mhc_post(p["mhc_ffn"], state, out2, cfg)
         else:
             state = state + out
             if spec.ffn != "none":
-                h2 = L.apply_norm(p["norm2"], state, cfg)
-                out2 = (L.apply_moe(p["ffn"], h2, cfg) if spec.ffn == "moe"
-                        else L.apply_mlp(p["ffn"], h2, spec.ffn))
-                state = state + out2
+                state = state + _apply_ffn(
+                    p["ffn"], spec, _norm(p["norm2"], state, cfg), cfg)
         return state, new_cache
 
     new_prelude = []
@@ -432,13 +463,7 @@ def _prefill_forward(params, cfg, batch, caches):
         body_key = "body"
     if cfg.hyper_connections:
         state = state.sum(0)
-    h = L.apply_norm(params["final_norm"], state, cfg)
-    if cfg.encoder_only:
-        logits = h @ params["head"]
-    elif cfg.tie_embeddings:
-        logits = h @ params["embed"].T
-    else:
-        logits = h @ params["lm_head"]
+    logits = _lm_head(params, cfg, state)
     return logits, {"prelude": new_prelude, body_key: body_val}
 
 

@@ -14,6 +14,25 @@ poison; a step-count deadline bounds the whole run.  ``run`` returns the
 requests (back-compat) and records a structured :class:`ServeReport` in
 ``last_report``.
 
+Tracing: ``run`` writes host spans into the profiler's trace
+(``jax.profiler.TraceAnnotation``, on the device planes' clock), and the two
+programs are jitted as ``serve_prefill`` and ``serve_decode``, so a trace
+shows ``jit_serve_prefill(...)`` and ``jit_serve_decode(...)`` modules.
+Spans, and the kwargs each carries:
+
+  serve.step         one loop iteration: fill, decode, hand-over  step
+  serve.admit        one admission, whole                 uid, slot, tokens
+  serve.prefill      the call into the prefill program (enqueue)  tokens
+  serve.slot_write   the write of the prefilled cache into a slot leaves
+  serve.first_token  the first token's wait and its scatter
+  serve.decode       the call into the decode program (enqueue)   active
+  serve.decode_sync  argmax and the wait for the step's tokens
+  serve.emit         the per-slot hand-over and retirement        done
+
+A shared-prefix admission has no ``serve.prefill``.  With no profiler
+running a span costs about a microsecond; its kwargs are encoded only
+while a trace is active.
+
 The straggler/deadline story for multi-host serving (and the ragged
 dispatch notes) live in DESIGN.md §5; this single-host engine is what the
 serve example + tests drive.
@@ -32,6 +51,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from ..core.resilience.faults import fault_point
 from ..models import transformer as T
@@ -400,12 +420,20 @@ class ServeEngine:
         self._admit_tick = 0
         self.last_token = jnp.zeros((batch_slots, 1), jnp.int32)
         self.last_report: Optional[ServeReport] = None
+        # arrays an admission writes into its slot (the serve.slot_write
+        # span's count)
+        self._slot_leaves = sum(isinstance(a, jax.Array)
+                                for a in jax.tree.leaves(self.caches))
 
-        self._decode = jax.jit(
-            lambda p, t, c: T.decode_step(p, cfg, t, c))
-        self._prefill = jax.jit(
-            lambda p, b: T.prefill(p, cfg, b, max_len),
-            static_argnames=())
+        # named, so that their programs are named in a profiler trace
+        def serve_decode(p, t, c):
+            return T.decode_step(p, cfg, t, c)
+
+        def serve_prefill(p, b):
+            return T.prefill(p, cfg, b, max_len)
+
+        self._decode = jax.jit(serve_decode)
+        self._prefill = jax.jit(serve_prefill)
 
     # ------------------------------------------------------------------
     def _admit(self, req: Request, slot: int) -> bool:
@@ -447,7 +475,8 @@ class ServeEngine:
                 rep.prefill_shared += 1
         else:
             batch = {"tokens": jnp.asarray(req.prompt[None], jnp.int32)}
-            logits, caches1 = self._prefill(self.params, batch)
+            with span("serve.prefill", tokens=len(req.prompt)):
+                logits, caches1 = self._prefill(self.params, batch)
             logits_last = logits[0, -1]
             if key is not None and left > 0:
                 self._prefix_memo[key] = (logits_last, caches1)
@@ -469,17 +498,19 @@ class ServeEngine:
                 c_all, c_one.astype(c_all.dtype),
                 (0, slot) + (0,) * (c_all.ndim - 2))
 
-        self.caches = jax.tree.map(write_leaf, self.caches, caches1,
-                                   is_leaf=lambda x: x is None or
-                                   isinstance(x, int))
-        nxt = int(jnp.argmax(logits_last))
-        req.generated.append(nxt)
-        if req.max_new_tokens <= 1 or (
-                req.eos_id is not None and nxt == req.eos_id):
-            # first token is the last: retire now, leave the slot free
-            req.done = True
-            return True
-        self.last_token = self.last_token.at[slot, 0].set(nxt)
+        with span("serve.slot_write", leaves=self._slot_leaves):
+            self.caches = jax.tree.map(write_leaf, self.caches, caches1,
+                                       is_leaf=lambda x: x is None or
+                                       isinstance(x, int))
+        with span("serve.first_token"):
+            nxt = int(jnp.argmax(logits_last))
+            req.generated.append(nxt)
+            if req.max_new_tokens <= 1 or (
+                    req.eos_id is not None and nxt == req.eos_id):
+                # first token is the last: retire now, leave the slot free
+                req.done = True
+                return True
+            self.last_token = self.last_token.at[slot, 0].set(nxt)
         self.slot_req[slot] = req
         self.slot_remaining[slot] = req.max_new_tokens - 1
         self.slot_len[slot] = len(req.prompt)
@@ -578,93 +609,104 @@ class ServeEngine:
                 self._slot_freed_at[b] = t_run
         active = lambda: any(r is not None for r in self.slot_req)  # noqa
         while queue or active():
-            if deadline_s is not None and \
-                    self.clock() - t_run >= deadline_s:
-                self._deadline_fail(
-                    queue, f"wall-clock deadline {deadline_s:g}s "
-                           f"exhausted", report)
-                break
-            # fill free slots (admission failures retry, then isolate)
-            for b in range(self.B):
-                while self.slot_req[b] is None and queue:
-                    req = queue.popleft()
+            with span("serve.step", step=report.decode_steps):
+                if deadline_s is not None and \
+                        self.clock() - t_run >= deadline_s:
+                    self._deadline_fail(
+                        queue, f"wall-clock deadline {deadline_s:g}s "
+                               f"exhausted", report)
+                    break
+                # fill free slots (admission failures retry, then isolate)
+                for b in range(self.B):
+                    while self.slot_req[b] is None and queue:
+                        req = queue.popleft()
+                        try:
+                            with span("serve.admit", uid=req.uid, slot=b,
+                                      tokens=len(req.prompt)):
+                                retired = self._admit(req, b)
+                        except Exception as e:  # noqa: BLE001 — isolate
+                            n = admit_attempts.get(req.uid, 0) + 1
+                            admit_attempts[req.uid] = n
+                            err = f"{type(e).__name__}: {e}"
+                            if n <= admit_retries:
+                                report.admit_retries += 1
+                                report.requeues += 1
+                                queue.append(req)   # retry behind the queue
+                            else:
+                                self._fail_request(req, "admit", err, report)
+                            continue
+                        if retired:                 # EOS at admission
+                            report.completed.append(req.uid)
+                            continue
+                        break                       # slot occupied
+                if not active():
+                    if queue:
+                        continue    # everything admitted so far failed/EOSed
+                    break
+                # resolve this step's fused decode kernel through the
+                # bucketed fast path (DESIGN.md §15).  Warmed: a pure cache
+                # materialize.  Any resolution failure is CONTAINED — the
+                # jitted decode step below must never be broken by the
+                # fastpath.
+                if self.fastpath is not None:
+                    occupied = [b for b in range(self.B)
+                                if self.slot_req[b] is not None]
+                    kv = min(int(self.slot_len[occupied].max()) + 1,
+                             self.max_len)
                     try:
-                        retired = self._admit(req, b)
-                    except Exception as e:  # noqa: BLE001 — isolate request
-                        n = admit_attempts.get(req.uid, 0) + 1
-                        admit_attempts[req.uid] = n
-                        err = f"{type(e).__name__}: {e}"
-                        if n <= admit_retries:
-                            report.admit_retries += 1
-                            report.requeues += 1
-                            queue.append(req)       # retry behind the queue
-                        else:
-                            self._fail_request(req, "admit", err, report)
-                        continue
-                    if retired:                     # EOS at admission
-                        report.completed.append(req.uid)
-                        continue
-                    break                           # slot occupied
-            if not active():
-                if queue:
-                    continue        # everything admitted so far failed/EOSed
-                break
-            # resolve this step's fused decode kernel through the bucketed
-            # fast path (DESIGN.md §15).  Warmed: a pure cache materialize.
-            # Any resolution failure is CONTAINED — the jitted decode step
-            # below must never be broken by the fastpath.
-            if self.fastpath is not None:
-                occupied = [b for b in range(self.B)
-                            if self.slot_req[b] is not None]
-                kv = min(int(self.slot_len[occupied].max()) + 1,
-                         self.max_len)
-                try:
-                    self.fastpath.resolve(self.B, kv)
-                except Exception:  # noqa: BLE001 — isolate the fastpath
-                    report.fastpath_errors += 1
-            # one batched decode step (retried; then poison isolation)
-            step_err = None
-            for attempt in range(decode_retries + 1):
-                try:
-                    fault_point("serve.decode",
-                                token=f"step={report.decode_steps}")
-                    logits, caches = self._decode(self.params,
-                                                  self.last_token,
-                                                  self.caches)
-                    step_err = None
-                    break
-                except Exception as e:  # noqa: BLE001
-                    step_err = f"{type(e).__name__}: {e}"
-                    if attempt < decode_retries:
-                        report.decode_retries += 1
-            if step_err is not None:
-                # decode keeps crashing: evict the newest admission and
-                # try again next loop — the engine survives, the poison
-                # request is reported
-                if not self._evict_newest(step_err, report):
-                    break
-                continue
-            self.caches = caches
-            report.decode_steps += 1
-            nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-            self.last_token = nxt[:, None]
-            nxt_host = np.asarray(nxt)
-            for b in range(self.B):
-                req = self.slot_req[b]
-                if req is None:
+                        self.fastpath.resolve(self.B, kv)
+                    except Exception:  # noqa: BLE001 — isolate the fastpath
+                        report.fastpath_errors += 1
+                # one batched decode step (retried; then poison isolation)
+                step_err = None
+                n_active = sum(r is not None for r in self.slot_req)
+                for attempt in range(decode_retries + 1):
+                    try:
+                        fault_point("serve.decode",
+                                    token=f"step={report.decode_steps}")
+                        with span("serve.decode", active=n_active):
+                            logits, caches = self._decode(self.params,
+                                                          self.last_token,
+                                                          self.caches)
+                        step_err = None
+                        break
+                    except Exception as e:  # noqa: BLE001
+                        step_err = f"{type(e).__name__}: {e}"
+                        if attempt < decode_retries:
+                            report.decode_retries += 1
+                if step_err is not None:
+                    # decode keeps crashing: evict the newest admission and
+                    # try again next loop — the engine survives, the poison
+                    # request is reported
+                    if not self._evict_newest(step_err, report):
+                        break
                     continue
-                tok = int(nxt_host[b])
-                req.generated.append(tok)
-                self.slot_remaining[b] -= 1
-                self.slot_len[b] += 1
-                if self.slot_remaining[b] <= 0 or (
-                        req.eos_id is not None and tok == req.eos_id):
-                    report.completed.append(req.uid)
-                    self._retire(b)
-            if report.decode_steps >= max_steps:
-                self._deadline_fail(
-                    queue, f"step budget {max_steps} exhausted", report)
-                break
+                self.caches = caches
+                report.decode_steps += 1
+                with span("serve.decode_sync"):
+                    nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+                    self.last_token = nxt[:, None]
+                    nxt_host = np.asarray(nxt)
+                with span("serve.emit") as emit:
+                    n_done = len(report.completed)
+                    for b in range(self.B):
+                        req = self.slot_req[b]
+                        if req is None:
+                            continue
+                        tok = int(nxt_host[b])
+                        req.generated.append(tok)
+                        self.slot_remaining[b] -= 1
+                        self.slot_len[b] += 1
+                        if self.slot_remaining[b] <= 0 or (
+                                req.eos_id is not None
+                                and tok == req.eos_id):
+                            report.completed.append(req.uid)
+                            self._retire(b)
+                    emit.set_metadata(done=len(report.completed) - n_done)
+                if report.decode_steps >= max_steps:
+                    self._deadline_fail(
+                        queue, f"step budget {max_steps} exhausted", report)
+                    break
         self._prefix_memo = OrderedDict()
         self._prefix_counts = {}
         return requests

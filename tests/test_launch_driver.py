@@ -47,3 +47,11 @@ def test_serve_exit_code_reflects_failed_requests():
     # every prefill crashes: both requests fail after their retries
     with inject(FaultPlan([FaultSpec("serve.admit", times=None)])):
         assert serve.main(argv) == 1
+
+
+def test_serve_profile_writes_a_trace(tmp_path):
+    from repro.launch import serve
+    argv = ["--requests", "2", "--max-new", "2", "--prompt-len", "4", "6",
+            "--no-fastpath", "--profile", str(tmp_path)]
+    assert serve.main(argv) == 0
+    assert list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))

@@ -310,6 +310,84 @@ def test_prefix_memo_lru_cap_evicts_and_stays_bit_identical(env):
     assert capped == roomy == off               # bit-identical throughout
 
 
+# ---------------------------------------------------------------------------
+# Tracing: the engine's serve.* host spans
+# ---------------------------------------------------------------------------
+
+SERVE_SPANS = {"serve.step", "serve.admit", "serve.prefill",
+               "serve.slot_write", "serve.first_token", "serve.decode",
+               "serve.decode_sync", "serve.emit"}
+
+
+def _serve_spans(log_dir):
+    """(name, start, end, stats, line) of every serve.* span in the one
+    trace under ``log_dir``."""
+    import glob
+    from jax.profiler import ProfileData
+    [path] = glob.glob(str(log_dir / "plugins" / "profile" / "*" /
+                           "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for k, ln in enumerate(plane.lines):
+            out += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats), (plane.name, k))
+                    for e in ln.events if e.name.startswith("serve.")]
+    return out
+
+
+def test_engine_spans_nest_and_leave_tokens_alone(env, tmp_path):
+    cfg, params = env
+    rng = np.random.RandomState(19)
+    prompts = [rng.randint(0, cfg.vocab, n).astype(np.int32)
+               for n in (8, 12, 6)]
+
+    def serve(traced):
+        eng = ServeEngine(params, cfg, batch_slots=2, max_len=32,
+                          decode_fastpath=False)
+        # the third request shares the first's prompt: no prefill of its own
+        reqs = [Request(uid=10 + i, prompt=prompts[k].copy(),
+                        max_new_tokens=3)
+                for i, k in enumerate((0, 1, 0, 2))]
+        if traced:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+                eng.run(reqs)
+        else:
+            eng.run(reqs)
+        return eng.last_report, [r.generated for r in reqs]
+
+    _, plain = serve(False)
+    rep, traced = serve(True)
+    assert traced == plain and rep.ok
+    spans = _serve_spans(tmp_path)
+    assert {s[0] for s in spans} == SERVE_SPANS
+    by = {n: [s for s in spans if s[0] == n] for n in SERVE_SPANS}
+
+    def inside(child, parents):
+        return [p for p in parents if p[4] == child[4]
+                and p[1] <= child[1] and child[2] <= p[2]]
+
+    for name, parent in (("serve.prefill", "serve.admit"),
+                         ("serve.slot_write", "serve.admit"),
+                         ("serve.first_token", "serve.admit"),
+                         ("serve.admit", "serve.step"),
+                         ("serve.decode", "serve.step"),
+                         ("serve.decode_sync", "serve.step"),
+                         ("serve.emit", "serve.step")):
+        for s in by[name]:
+            assert len(inside(s, by[parent])) == 1, (name, s)
+    admits = by["serve.admit"]
+    assert sorted(s[3]["uid"] for s in admits) == [10, 11, 12, 13]
+    assert all({"slot", "tokens"} <= set(s[3]) for s in admits)
+    assert len(by["serve.prefill"]) == 3 and rep.prefill_shared == 1
+    assert len(by["serve.decode"]) == rep.decode_steps
+    assert all(1 <= s[3]["active"] <= 2 for s in by["serve.decode"])
+    assert all(s[3]["leaves"] > 0 for s in by["serve.slot_write"])
+    assert sum(s[3]["done"] for s in by["serve.emit"]) == \
+        len(rep.completed)
+
+
 def test_traffic_model_exact_for_relu():
     from repro.bench import suite
     from repro.bench.model import analyze_program, _padded_shapes_for
