@@ -15,15 +15,18 @@ requests (back-compat) and records a structured :class:`ServeReport` in
 ``last_report``.
 
 Tracing: ``run`` writes host spans into the profiler's trace
-(``jax.profiler.TraceAnnotation``, on the device planes' clock), and the two
-programs are jitted as ``serve_prefill`` and ``serve_decode``, so a trace
-shows ``jit_serve_prefill(...)`` and ``jit_serve_decode(...)`` modules.
+(``jax.profiler.TraceAnnotation``, on the device planes' clock), and the
+three programs are jitted as ``serve_prefill``, ``serve_slot_write`` and
+``serve_decode``, so a trace shows ``jit_serve_prefill(...)``,
+``jit_serve_slot_write(...)`` and ``jit_serve_decode(...)`` modules.  The
+slot write donates the batch cache, so an admission updates it in place
+with one launch.
 Spans, and the kwargs each carries:
 
   serve.step         one loop iteration: fill, decode, hand-over  step
   serve.admit        one admission, whole                 uid, slot, tokens
   serve.prefill      the call into the prefill program (enqueue)  tokens
-  serve.slot_write   the write of the prefilled cache into a slot leaves
+  serve.slot_write   the call into the slot-write program  leaves, donated
   serve.first_token  the first token's wait and its scatter
   serve.decode       the call into the decode program (enqueue)   active
   serve.decode_sync  argmax and the wait for the step's tokens
@@ -355,11 +358,19 @@ class ServeReport:
     prefill_shared: int = 0         # admissions served from a shared prefix
     prefill_memo_evictions: int = 0  # LRU evictions from the prefix memo
     fastpath_errors: int = 0        # contained fastpath-resolution failures
+    slot_writes: int = 0            # prefilled caches written into a slot
+    slot_writes_donated: int = 0    # ... that updated the batch cache in place
     slot_refill_s: List[float] = field(default_factory=list)
 
     @property
     def ok(self) -> bool:
         return not self.failed and not self.deadline_hit
+
+
+def _opaque(x) -> bool:
+    """A cache leaf that the slot write leaves alone: an ``int`` or a
+    ``None``."""
+    return x is None or isinstance(x, int)
 
 
 class ServeEngine:
@@ -420,10 +431,6 @@ class ServeEngine:
         self._admit_tick = 0
         self.last_token = jnp.zeros((batch_slots, 1), jnp.int32)
         self.last_report: Optional[ServeReport] = None
-        # arrays an admission writes into its slot (the serve.slot_write
-        # span's count)
-        self._slot_leaves = sum(isinstance(a, jax.Array)
-                                for a in jax.tree.leaves(self.caches))
 
         # named, so that their programs are named in a profiler trace
         def serve_decode(p, t, c):
@@ -432,8 +439,25 @@ class ServeEngine:
         def serve_prefill(p, b):
             return T.prefill(p, cfg, b, max_len)
 
+        def serve_slot_write(c_all, c_one, slot):
+            # each leaf is (B, ...) <- (1, ...) or (repeats, B, ...) <-
+            # (repeats, 1, ...): the slot's axis is the one whose size
+            # differs (none when B == 1, and then slot is 0)
+            def write(a, o):
+                axis = next((i for i, (n, m) in enumerate(zip(a.shape,
+                                                              o.shape))
+                             if n != m), 0)
+                start = [0] * a.ndim
+                start[axis] = slot
+                return jax.lax.dynamic_update_slice(a, o.astype(a.dtype),
+                                                    start)
+            return [write(a, o) for a, o in zip(c_all, c_one)]
+
         self._decode = jax.jit(serve_decode)
         self._prefill = jax.jit(serve_prefill)
+        # the batch cache is donated: the write is in place.  The
+        # one-request cache is not: the prefix memo reuses it.
+        self._slot_write = jax.jit(serve_slot_write, donate_argnums=0)
 
     # ------------------------------------------------------------------
     def _admit(self, req: Request, slot: int) -> bool:
@@ -485,23 +509,22 @@ class ServeEngine:
                     if rep is not None:
                         rep.prefill_memo_evictions += 1
 
-        # slot write: leaf shapes are (B, ...) or (repeats, B, ...)
-        def write_leaf(c_all, c_one):
-            if isinstance(c_one, int) or c_one is None:
-                return c_all
-            if c_all.ndim == c_one.ndim:       # (B, ...) <- (1, ...)
-                return jax.lax.dynamic_update_slice(
-                    c_all, c_one.astype(c_all.dtype),
-                    (slot,) + (0,) * (c_all.ndim - 1))
-            # (repeats, B, ...) <- (repeats, 1, ...)
-            return jax.lax.dynamic_update_slice(
-                c_all, c_one.astype(c_all.dtype),
-                (0, slot) + (0,) * (c_all.ndim - 2))
-
-        with span("serve.slot_write", leaves=self._slot_leaves):
-            self.caches = jax.tree.map(write_leaf, self.caches, caches1,
-                                       is_leaf=lambda x: x is None or
-                                       isinstance(x, int))
+        # slot write: one program over the array leaves; a recurrent
+        # cache's int and None leaves stay as they are
+        flat, tree = jax.tree.flatten(self.caches, is_leaf=_opaque)
+        ones = jax.tree.leaves(caches1, is_leaf=_opaque)
+        idx = [i for i, o in enumerate(ones) if not _opaque(o)]
+        with span("serve.slot_write", leaves=len(idx)) as sw:
+            new = self._slot_write([flat[i] for i in idx],
+                                   [ones[i] for i in idx], np.int32(slot))
+            donated = int(bool(idx) and flat[idx[0]].is_deleted())
+            sw.set_metadata(donated=donated)
+        for i, a in zip(idx, new):
+            flat[i] = a
+        self.caches = jax.tree.unflatten(tree, flat)
+        if rep is not None:
+            rep.slot_writes += 1
+            rep.slot_writes_donated += donated
         with span("serve.first_token"):
             nxt = int(jnp.argmax(logits_last))
             req.generated.append(nxt)

@@ -1,4 +1,6 @@
 """Serving engine + performance-model sanity."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +9,7 @@ import pytest
 from repro.configs import get_config
 from repro.models import transformer as T
 from repro.serving import (DecodeFastPath, Request, ServeEngine,
-                           decode_bucket, kv_bucket_ladder,
+                           ServeReport, decode_bucket, kv_bucket_ladder,
                            load_warmup_manifest, pow2_bucket,
                            warm_from_manifest, warm_kernel_cache)
 
@@ -240,7 +242,11 @@ def test_prefix_sharing_prefills_once_per_distinct_prompt(env):
                       decode_fastpath=False)
     prefills = []
     orig = eng._prefill
-    eng._prefill = lambda p, b: (prefills.append(1) or orig(p, b))
+
+    def prefill(p, b):
+        prefills.append(orig(p, b))
+        return prefills[-1]
+    eng._prefill = prefill
     reqs = [Request(uid=i, prompt=shared.copy(), max_new_tokens=4)
             for i in range(3)]
     reqs.append(Request(uid=3, prompt=other, max_new_tokens=4))
@@ -249,6 +255,11 @@ def test_prefix_sharing_prefills_once_per_distinct_prompt(env):
     assert rep.ok and rep.prefill_shared == 2    # samples 2 and 3 broadcast
     assert len(prefills) == 2                    # one per DISTINCT prompt
     assert eng._prefix_memo == {}                # memo dropped after the run
+    # the slot write donates the batch cache, never the memoized
+    # one-request cache: it is still readable after the admissions
+    memo = jax.tree.leaves(prefills[0][1])
+    assert not any(a.is_deleted() for a in memo)
+    assert all(np.isfinite(np.asarray(a, np.float32)).all() for a in memo)
     # greedy: every sample of the shared prompt generates the same tokens
     assert reqs[0].generated == reqs[1].generated == reqs[2].generated
 
@@ -311,6 +322,79 @@ def test_prefix_memo_lru_cap_evicts_and_stays_bit_identical(env):
 
 
 # ---------------------------------------------------------------------------
+# Admission: the slot write
+# ---------------------------------------------------------------------------
+
+def _opaque(x):
+    return x is None or isinstance(x, int)
+
+
+def _eager_slot_write(c_all, c_one, slot):
+    """The per-leaf eager write an admission made before its write was one
+    program: the slot's axis is 0, and 1 under the stacked ``body``
+    (``(repeats, B, ...)``); int and None leaves pass through."""
+    def leaf(path, a, o):
+        if _opaque(o):
+            return a
+        axis = 1 if getattr(path[0], "key", None) == "body" else 0
+        start = [0] * a.ndim
+        start[axis] = slot
+        return jax.lax.dynamic_update_slice(a, o.astype(a.dtype), start)
+    return jax.tree_util.tree_map_with_path(leaf, c_all, c_one,
+                                            is_leaf=_opaque)
+
+
+@pytest.mark.parametrize("arch,unroll,opaque", [
+    ("internlm2-1.8b", True, False),      # per-layer int8 KV + scales
+    ("internlm2-1.8b", False, False),     # stacked (repeats, B, ...)
+    ("xlstm-1.3b", True, True),           # mLSTM/sLSTM state + int leaves
+], ids=["unrolled_int8", "stacked", "recurrent"])
+def test_slot_write_one_donated_program_matches_eager(arch, unroll, opaque):
+    cfg = dataclasses.replace(get_config(arch, smoke=True),
+                              serve_unroll_layers=unroll)
+    if arch == "internlm2-1.8b":
+        assert cfg.kv_cache_dtype == "int8"
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    B, max_len = 3, 16
+
+    def with_opaque(c):     # a recurrent step count and an absent state
+        return ({**c, "prelude": [*c["prelude"], {"step": 5, "h": None}]}
+                if opaque else c)
+
+    eng = ServeEngine(params, cfg, batch_slots=B, max_len=max_len,
+                      decode_fastpath=False)
+    eng.caches = with_opaque(eng.caches)
+    prefill, ones = eng._prefill, []
+
+    def recorded_prefill(p, b):
+        logits, c = prefill(p, b)
+        ones.append(with_opaque(c))
+        return logits, ones[-1]
+    eng._prefill = recorded_prefill
+    eng.last_report = rep = ServeReport()
+    want = with_opaque(T.init_caches(cfg, B, max_len))
+    rng = np.random.RandomState(23)
+    for slot in (2, 0, 1):
+        before = [a for a in jax.tree.leaves(eng.caches) if not _opaque(a)]
+        prompt = rng.randint(0, cfg.vocab, 5 + slot).astype(np.int32)
+        eng._admit(Request(uid=slot, prompt=prompt, max_new_tokens=4), slot)
+        want = _eager_slot_write(want, ones[-1], slot)
+        assert all(a.is_deleted() for a in before)   # updated in place
+    got_l, got_t = jax.tree.flatten(eng.caches, is_leaf=_opaque)
+    want_l, want_t = jax.tree.flatten(want, is_leaf=_opaque)
+    assert got_t == want_t
+    for g, w in zip(got_l, want_l):
+        if _opaque(w):
+            assert g is w or g == w
+        else:
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # one program serves every slot
+    assert eng._slot_write._cache_size() == 1
+    assert rep.slot_writes == rep.slot_writes_donated == 3
+
+
+# ---------------------------------------------------------------------------
 # Tracing: the engine's serve.* host spans
 # ---------------------------------------------------------------------------
 
@@ -319,9 +403,9 @@ SERVE_SPANS = {"serve.step", "serve.admit", "serve.prefill",
                "serve.decode_sync", "serve.emit"}
 
 
-def _serve_spans(log_dir):
-    """(name, start, end, stats, line) of every serve.* span in the one
-    trace under ``log_dir``."""
+def _trace_events(log_dir):
+    """(name, start, end, stats, line) of every event in the one trace
+    under ``log_dir``."""
     import glob
     from jax.profiler import ProfileData
     [path] = glob.glob(str(log_dir / "plugins" / "profile" / "*" /
@@ -330,8 +414,7 @@ def _serve_spans(log_dir):
     for plane in ProfileData.from_file(path).planes:
         for k, ln in enumerate(plane.lines):
             out += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
-                     dict(e.stats), (plane.name, k))
-                    for e in ln.events if e.name.startswith("serve.")]
+                     dict(e.stats), (plane.name, k)) for e in ln.events]
     return out
 
 
@@ -355,12 +438,14 @@ def test_engine_spans_nest_and_leave_tokens_alone(env, tmp_path):
                 eng.run(reqs)
         else:
             eng.run(reqs)
-        return eng.last_report, [r.generated for r in reqs]
+        return eng, [r.generated for r in reqs]
 
     _, plain = serve(False)
-    rep, traced = serve(True)
+    eng, traced = serve(True)
+    rep = eng.last_report
     assert traced == plain and rep.ok
-    spans = _serve_spans(tmp_path)
+    events = _trace_events(tmp_path)
+    spans = [e for e in events if e[0].startswith("serve.")]
     assert {s[0] for s in spans} == SERVE_SPANS
     by = {n: [s for s in spans if s[0] == n] for n in SERVE_SPANS}
 
@@ -384,6 +469,19 @@ def test_engine_spans_nest_and_leave_tokens_alone(env, tmp_path):
     assert len(by["serve.decode"]) == rep.decode_steps
     assert all(1 <= s[3]["active"] <= 2 for s in by["serve.decode"])
     assert all(s[3]["leaves"] > 0 for s in by["serve.slot_write"])
+    # every write updated the batch cache in place, in its own program
+    assert all(s[3]["donated"] == 1 for s in by["serve.slot_write"])
+    assert rep.slot_writes == rep.slot_writes_donated == \
+        len(by["serve.slot_write"]) == 4
+    # ... with one launch each, of the program named jit_serve_slot_write
+    calls = [e for e in events if e[0] == "PjitFunction(serve_slot_write)"]
+    for s in by["serve.slot_write"]:
+        mine = [c for c in calls if inside(c, [s])]
+        # the first call nests its slow path in its fast one
+        assert len([c for c in mine if len(inside(c, mine)) == 1]) == 1
+    leaves = jax.tree.leaves(eng.caches)
+    assert "module @jit_serve_slot_write" in eng._slot_write.lower(
+        leaves, leaves, np.int32(0)).as_text()
     assert sum(s[3]["done"] for s in by["serve.emit"]) == \
         len(rep.completed)
 
