@@ -7,6 +7,8 @@ weights on the device, builds the engine, and serves one short request
 per prompt length of the mix through the same ``run``, so that every
 program the window uses is compiled or loaded before it opens.  The cell's
 own file (``cells/<cell>.json``) gives its clients, one per engine slot.
+The configuration's architecture module (``archs/<bench_arch>.py``) gives
+the program's model, the weights and the reference.
 """
 from __future__ import annotations
 
@@ -16,7 +18,7 @@ from functools import partial
 
 import numpy as np
 
-from chipbench.lib import model, reference, traffic
+from chipbench.lib import reference, traffic
 from chipbench.lib.timeline import Timeline, WindowClock, window_stats
 
 # a program lowered inside the process: compiled, or loaded from the cache
@@ -112,23 +114,23 @@ def sample_for_check(reqs, completed: set, seed: int, want_tokens: int):
 
 
 def run(conf: dict, mix: dict, own: dict, seed: int, seconds: float,
-        trace_dir=None, fault=None, t_start: float | None = None,
+        arch, trace_dir=None, fault=None, t_start: float | None = None,
         controls=()) -> dict:
-    """One run of a serving cell.  ``trace_dir`` turns the profiler on over
-    the window's first ``mix['trace_seconds']``.  ``fault`` (tests only)
-    is called with the engine before the window, to break the timed path.
-    ``controls`` ("int8", "fp8") also reads those controls' gaps on the
-    same sample (``calibrate.py``, tests).  Returns the run's record for
-    the metric readers."""
+    """One run of a serving cell whose model is ``arch``'s.  ``trace_dir``
+    turns the profiler on over the window's first ``mix['trace_seconds']``.
+    ``fault`` (tests only) is called with the engine before the window, to
+    break the timed path.  ``controls`` ("int8", "fp8") also reads those
+    controls' gaps on the same sample (``calibrate.py``, tests).  Returns
+    the run's record for the metric readers."""
     import jax
     from repro.serving import Request, ServeEngine
 
     t_start = time.perf_counter() if t_start is None else t_start
     counter = CompileCounter()
-    cfg = model.arch_config(conf)
+    cfg = arch.arch_config(conf)
     max_len = traffic.max_len(mix)
     B = int(own["clients"])
-    params = jax.jit(partial(model.make_params, conf))(model.jax_key(seed))
+    params = jax.jit(partial(arch.make_params, conf))(traffic.jax_key(seed))
     jax.block_until_ready(params)
 
     clock = WindowClock()
@@ -186,8 +188,9 @@ def run(conf: dict, mix: dict, own: dict, seed: int, seconds: float,
     gaps = {q: [] for q in (None, *controls)}
     for r in sample:
         g, c = reference.served_gaps(
-            params, conf, np.asarray(r.prompt), np.asarray(list(r.generated)),
-            max_len, mix["output"]["max"], controls)
+            arch, params, conf, np.asarray(r.prompt),
+            np.asarray(list(r.generated)), max_len, mix["output"]["max"],
+            controls)
         for q, gq in ((None, g), *c.items()):
             gaps[q].append(gq)
     gaps = {q: np.concatenate(v) for q, v in gaps.items() if v}
@@ -196,8 +199,8 @@ def run(conf: dict, mix: dict, own: dict, seed: int, seconds: float,
            for q in (None, *controls)}
     t0 = rec.opened_at
     return {
-        "conf": conf, "setup_s": t0 - t_start, "window": (t0, t0 + seconds),
-        "stats": stats, "record": rec,
+        "arch": arch, "conf": conf, "setup_s": t0 - t_start,
+        "window": (t0, t0 + seconds), "stats": stats, "record": rec,
         "prompt_lens": [len(s.prompt) for s in specs],
         "compiles_in_window": counter.between(t0, t0 + seconds),
         "memory_peak_bytes": peak,

@@ -39,6 +39,12 @@ def rng_for(seed: int, stream: str) -> np.random.Generator:
     return np.random.default_rng(np.random.SeedSequence(words))
 
 
+def jax_key(seed: int):
+    """The key the weights are made from."""
+    import jax
+    return jax.random.PRNGKey(int(rng_for(seed, "weights").integers(2**31)))
+
+
 def lognormal_quantiles(median: float, sigma: float, n: int) -> np.ndarray:
     """The n stratified quantiles (i + 0.5) / n of a lognormal."""
     z = np.array([NormalDist().inv_cdf((i + 0.5) / n) for i in range(n)])

@@ -7,8 +7,11 @@ Everything is found by name from ``BENCHMARK.json`` at the checkout root:
 the cell's configuration (``chipbench/configs/<config>.json``), its
 traffic mix (``chipbench/mixes/<traffic>.json``, whose ``kind`` names the
 driver ``chipbench/drivers/<kind>.py``), the cell's own file
-(``chipbench/cells/<cell>.json``: its clients and its output limits) and
-one reader per metric (``chipbench/metrics/<metric>.py``).  With ``--trace 0`` the line holds
+(``chipbench/cells/<cell>.json``: its clients and its output limits),
+the architecture module the configuration names under ``bench_arch``
+(``chipbench/archs/<bench_arch>.py``: the program's model, the weights,
+the reference and the work counts) and one reader per metric
+(``chipbench/metrics/<metric>.py``).  With ``--trace 0`` the line holds
 the cell's end-to-end metrics; with ``--trace 1`` the profiler runs over
 the first part of the window and the line holds its per-layer metrics,
 the device's busy time and a breakdown.
@@ -61,6 +64,16 @@ def resolve(spec: dict, workload: str, root: Path = ROOT):
     return cell, conf, mix, own
 
 
+def load_arch(conf: dict, root: Path = ROOT):
+    """The architecture module the configuration names: everything about
+    its model that depends on the layers."""
+    if "bench_arch" not in conf:
+        raise KeyError(f"configuration {conf.get('name')!r} has no "
+                       "'bench_arch' key naming its module under "
+                       f"{BENCH.name}/archs/")
+    return load("archs", conf["bench_arch"], root)
+
+
 def cell_metrics(spec: dict, workload: str, trace: bool) -> list[dict]:
     """The metrics this cell reports in a run of this kind."""
     group = spec["per_layer"] if trace else spec["end_to_end"]
@@ -69,7 +82,8 @@ def cell_metrics(spec: dict, workload: str, trace: bool) -> list[dict]:
 
 def load(kind: str, name: str, root: Path = ROOT):
     """The module ``chipbench/<kind>/<name>.py`` of the checkout at
-    ``root``: a metric's reader, or the driver of a mix's kind."""
+    ``root``: a metric's reader, the driver of a mix's kind, or a
+    configuration's architecture."""
     path = root / BENCH.name / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
         f"chipbench_{kind}_{name.replace('.', '_')}", path)
@@ -137,8 +151,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     if trace_dir is not None and trace_dir.exists():
         shutil.rmtree(trace_dir)
     driver = load("drivers", mix["kind"], root)
-    record = driver.run(conf, mix, own, seed, seconds, trace_dir=trace_dir,
-                        fault=fault, t_start=T_START, controls=controls)
+    record = driver.run(conf, mix, own, seed, seconds, load_arch(conf, root),
+                        trace_dir=trace_dir, fault=fault, t_start=T_START,
+                        controls=controls)
 
     from chipbench.lib import trace as tr
     ctx = RunContext(record, peak,

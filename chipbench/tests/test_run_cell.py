@@ -1,13 +1,13 @@
 """A whole run of a tiny serving cell on the CPU, the chip check skipped:
-the result line's schema, and a cell, mix, driver and metric that exist
-only as added files found by name."""
+the result line's schema, and a cell, mix, driver, architecture and
+metric that exist only as added files found by name."""
 import json
 
 import pytest
 
 from chipbench import run
-from chipbench.tests.tiny import CELL, TINY_MIX, TINY_OWN, FakeDevice, \
-    make_root
+from chipbench.tests.tiny import CELL, TINY_CONF, TINY_MIX, TINY_OWN, \
+    FakeDevice, make_root
 
 SEED = 2**31 + 77
 
@@ -86,7 +86,7 @@ STUB_DRIVER = """
 from chipbench.lib.timeline import Record, window_stats
 
 
-def run(conf, mix, own, seed, seconds, trace_dir=None, fault=None,
+def run(conf, mix, own, seed, seconds, arch, trace_dir=None, fault=None,
         t_start=None, controls=()):
     times = [[0.0, 0.5], [0.2, 0.6], [0.7, 0.9]]
     rec = Record(times=times, clients=2, completions=[0.5],
@@ -118,3 +118,57 @@ def test_added_driver_is_found_by_name(tmp_path):
     assert out["correct"] is True and out["attempted"] == 1
     assert out["metrics"] == {"setup_s": {"value": 1.5, "unit": "s"}}
     assert out["checks"] == {"steps_off": {"value": 0.0, "limit": 0.0}}
+
+# an architecture module that hands every call on to archs/dense.py and
+# notes its name in a file beside itself
+STUB_ARCH = """
+from pathlib import Path
+
+from chipbench.run import load
+
+_dense = load("archs", "dense", Path(__file__).resolve().parents[2])
+CALLS = Path(__file__).with_suffix(".calls")
+NAMES = ["arch_config", "make_params", "logits_at", "n_params",
+         "prefill_attention", "decode_attention"]
+
+
+def _noted(name):
+    def call(*args, **kwargs):
+        with CALLS.open("a") as f:
+            f.write(name + "\\n")
+        return getattr(_dense, name)(*args, **kwargs)
+    return call
+
+
+globals().update({name: _noted(name) for name in NAMES})
+"""
+
+
+def test_added_arch_is_found_by_name(tmp_path):
+    root = make_root(tmp_path)
+    bench = root / "chipbench"
+    (bench / "archs" / "stub_arch.py").write_text(STUB_ARCH)
+    (bench / "configs" / "tiny_stub.json").write_text(json.dumps(
+        dict(TINY_CONF, name="tiny_stub", bench_arch="stub_arch")))
+    (bench / "cells" / "tiny_stub.short.json").write_text(
+        json.dumps(TINY_OWN))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "tiny_stub", "source": "tests",
+                            "file": "chipbench/configs/tiny_stub.json",
+                            "reduced": [], "why": "tests"})
+    spec["workloads"] = [{"name": "tiny_stub.short", "config": "tiny_stub",
+                          "traffic": "short", "chips": 1, "why": "stub"}]
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "workloads" in m:
+            m["workloads"] = ["tiny_stub.short"]
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    out = run.run_cell("tiny_stub.short", SEED, 1.5, True, root=root,
+                       devices=[FakeDevice()])
+    # the weights, the reference that decided `correct` and the work that
+    # mfu.serve counts all came from the added module
+    assert out["correct"] is True
+    assert out["metrics"]["mfu.serve"]["value"] > 0
+    calls = (bench / "archs" / "stub_arch.calls").read_text().split()
+    assert set(calls) == {"arch_config", "make_params", "logits_at",
+                          "n_params", "prefill_attention",
+                          "decode_attention"}

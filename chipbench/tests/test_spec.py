@@ -1,6 +1,6 @@
 """BENCHMARK.json keeps to its contract, and every name in it finds its
-file: configuration, mix, driver, the cell's own file and metric
-reader."""
+file: configuration, its architecture module, mix, driver, the cell's own
+file and metric reader."""
 import json
 import re
 from pathlib import Path
@@ -36,6 +36,8 @@ def test_config_file(c):
     conf = json.loads((ROOT / c["file"]).read_text())
     assert conf["name"] == c["name"] and conf["source"] == c["source"]
     assert conf["reduced"] == c["reduced"]
+    assert (ROOT / "chipbench" / "archs" / f"{conf['bench_arch']}.py") \
+        .is_file()
     for key in c["reduced"]:
         assert not WIDTH.search(key)
         assert conf["published"][key] != conf[key]

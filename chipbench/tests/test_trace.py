@@ -6,7 +6,7 @@ import pytest
 from chipbench.lib import trace as tr
 from chipbench.lib.context import RunContext
 from chipbench.run import reader
-from chipbench.tests.test_work import CONF
+from chipbench.tests.test_work import CONF, DENSE
 
 MOSAIC_OP = ('%_run.1 = f32[512,128] custom-call(f32[512,128] %a), '
              'custom_call_target=\\"tpu_custom_call\\"')
@@ -64,8 +64,8 @@ def trace():
 
 @pytest.fixture
 def run(trace):
-    ctx = RunContext({"conf": CONF}, {"bf16_flops_per_s": 197e12,
-                                      "hbm_bytes_per_s": 819e9})
+    ctx = RunContext({"arch": DENSE, "conf": CONF},
+                     {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
     ctx.__dict__["trace"] = trace
     return ctx
 
@@ -120,8 +120,8 @@ def test_readers_on_the_trace(run, name, want):
 
 
 def test_readers_without_their_events_return_nothing():
-    ctx = RunContext({"conf": CONF}, {"bf16_flops_per_s": 1.0,
-                                      "hbm_bytes_per_s": 1.0})
+    ctx = RunContext({"arch": DENSE, "conf": CONF},
+                     {"bf16_flops_per_s": 1.0, "hbm_bytes_per_s": 1.0})
     ctx.__dict__["trace"] = None
     for name in ("decode_host_gap_ms", "decode_step_ms",
                  "prefill_ms_per_ktok", "idle_share.serve",

@@ -12,6 +12,7 @@ CELL = "tiny.short"
 TINY_CONF = {
     "name": "tiny", "source": "the program's internlm2-1.8b smoke preset",
     "program_arch": "internlm2-1.8b", "program_preset": "smoke",
+    "bench_arch": "dense",
     "num_hidden_layers": 2, "hidden_size": 128, "intermediate_size": 256,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
     "vocab_size": 512, "rms_norm_eps": 1e-06, "rope_theta": 1000000.0,
@@ -38,7 +39,7 @@ class FakeDevice:
 
 def make_root(tmp: Path, limit: float = 0.5) -> Path:
     """A checkout root whose BENCHMARK.json names only the tiny cell,
-    with the real drivers and metric readers."""
+    with the real drivers, architecture modules and metric readers."""
     spec = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     spec["configs"] = [{"name": "tiny", "source": TINY_CONF["source"],
                         "file": "chipbench/configs/tiny.json",
@@ -52,7 +53,7 @@ def make_root(tmp: Path, limit: float = 0.5) -> Path:
     bench = tmp / "chipbench"
     for sub in ("configs", "mixes", "cells"):
         (bench / sub).mkdir(parents=True, exist_ok=True)
-    for sub in ("metrics", "drivers"):
+    for sub in ("metrics", "drivers", "archs"):
         shutil.copytree(BENCH / sub, bench / sub,
                         ignore=shutil.ignore_patterns("__pycache__"),
                         dirs_exist_ok=True)
