@@ -33,10 +33,11 @@ from .ref import mha_reference
 def _chain_entry(Sq: int, Skv: int, D: int):
     """Compile the fused flash chain at one slice geometry.
 
-    Returns (entry, baked_scale): `entry(q2, k2, mask, v2)` computes
-    softmax(q2 @ k2.T * baked_scale + mask) @ v2 with f32 accumulation
-    (streaming online-softmax when the row does not fit VMEM, resident
-    single-visit otherwise; sequential staging if fusion refuses).
+    Returns (entry, baked_scale, pattern): `entry(q2, k2, mask, v2)`
+    computes softmax(q2 @ k2.T * baked_scale + mask) @ v2 with f32
+    accumulation; `pattern` is "streaming" (online softmax) when the row
+    does not fit VMEM, "resident" (single visit) otherwise, and None for
+    sequential staging if fusion refuses.
     """
     from ...core.fusion.chain import CHAINS, build_fused
     from ...core.lowering.pipeline import transcompile
@@ -45,7 +46,8 @@ def _chain_entry(Sq: int, Skv: int, D: int):
               "v": (Skv, D), "output": (Sq, D)}
     prog = build_fused(spec, shapes)
     art = transcompile(prog, verify_against_interp=False)
-    return art.entry, float(dict(spec.attrs)["scale"])
+    return art.entry, float(dict(spec.attrs)["scale"]), \
+        (prog.meta.get("fusion") or {}).get("pattern")
 
 
 # The chain lowers to the pipelined (BlockSpec) backend, the form Mosaic
@@ -87,7 +89,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
 
-    entry, baked = _chain_entry(_round_up(Sq), _round_up(Skv), D)
+    entry, baked, _ = _chain_entry(_round_up(Sq), _round_up(Skv), D)
 
     def pad_seq(x, s):
         x = jnp.asarray(x, jnp.float32)
@@ -136,8 +138,13 @@ def attention(q, k, v, *, causal: bool = True, sm_scale=None,
     """Framework entry point; `impl` in {"auto", "pallas", "xla"}."""
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "pallas" and logit_cap == 0.0:
+    if impl == "pallas" and logit_cap == 0.0 and _chain_entry(
+            _round_up(q.shape[1]), _round_up(k.shape[1]),
+            q.shape[-1])[2] == "resident":
         return flash_attention(q, k, v, causal, sm_scale)
+    # the chain's streaming form (rows too wide for VMEM: 4096 keys at
+    # head dim 192 or 256) does not lower on the chip, where Mosaic
+    # refuses its loads from pl.ANY references: the reference runs there
     return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                          logit_cap=logit_cap)
 
@@ -165,7 +172,7 @@ def decode_attention_fused(q, k_cache, v_cache, cache_len, *,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
 
-    entry, baked = _chain_entry(group, S, D)
+    entry, baked, _ = _chain_entry(group, S, D)
     # (B, 1, Hq, D) -> (B, Hkv, group, D): heads are consecutive blocks
     qf = (jnp.asarray(q, jnp.float32) * (sm_scale / baked)) \
         .reshape(B, Hkv, group, D)

@@ -19,6 +19,21 @@ class LayerSpec:
     ffn: str            # "swiglu" | "gelu" | "moe" | "none"
 
 
+@dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling (arXiv:2309.00071) in DeepSeek-V2's published
+    form (``rope_scaling`` of type ``yarn``): rotary frequencies blended
+    between interpolated and extrapolated by a linear ramp over the
+    rotary dimensions, and the attention's softmax scale multiplied by
+    ``mscale(factor, mscale_all_dim) ** 2``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
 @dataclass
 class ArchConfig:
     name: str
@@ -39,6 +54,8 @@ class ArchConfig:
     encoder_only: bool = False
     norm: str = "rmsnorm"                    # "rmsnorm" | "layernorm"
     rope_theta: float = 1.0e6
+    # None: plain rotary frequencies.  Read by MLA only.
+    rope_scaling: Optional[YaRN] = None
     tie_embeddings: bool = False
 
     # --- MoE ---
@@ -46,14 +63,17 @@ class ArchConfig:
     top_k: int = 0
     n_shared_experts: int = 0
     d_ff_expert: int = 0
-    # "dense" = masked-dense dispatch (DEFAULT: weight-local under EP
-    # sharding; O(E) flops/token).  "capacity" = sort-based sparse dispatch
-    # — O(top_k) flops/token in principle, but the global token argsort is
-    # un-shardable under jit/GSPMD, which REPLICATES dispatch+experts and
-    # measures 2.3x WORSE per-device flops (§Perf M1/M2, refuted
-    # hypothesis).  The production fix is shard_map-local routing +
-    # ragged all_to_all (DESIGN.md §5 follow-up).
+    # "dense": every expert runs every token, masked by the routing
+    # weights (weight-local under EP sharding; O(E) flops/token; the
+    # reference the grouped path is tested against).  "grouped": the
+    # (token, expert) pairs sorted by expert and run as grouped matrix
+    # products (jax.lax.ragged_dot), O(top_k) flops/token, no token
+    # dropped at any batch size.
     moe_impl: str = "dense"
+    # router: softmax over every expert, then the top_k of it.  True
+    # renormalises the top_k gates to sum to 1 (the same as a softmax over
+    # the top_k logits); DeepSeek-V2 publishes False.
+    norm_topk_prob: bool = True
 
     # --- MLA (DeepSeek-V2) ---
     mla: bool = False
@@ -88,11 +108,12 @@ class ArchConfig:
     # cache makes GSPMD involuntarily rematerialize (all-gather) the cache
     # every step — see EXPERIMENTS.md §Perf iteration 1.
     serve_unroll_layers: bool = True
-    # KV cache dtype: "model" (the model dtype) or "int8" — per-position
-    # per-head max-abs quantization.  DEFAULT int8: without it the 32k-decode
-    # cells exceed v5e HBM (qwen3: 137 GB temp vs 16 GB) and the memory
-    # roofline term is 2.8x worse (§Perf iteration 2).  GQA attention only;
-    # MLA caches are already latent-compressed.
+    # KV cache dtype of GQA/MHA attention: "model" (the model dtype) or
+    # "int8" — per-position per-head max-abs quantization.  DEFAULT int8:
+    # without it the 32k-decode cells exceed v5e HBM (qwen3: 137 GB temp
+    # vs 16 GB) and the memory roofline term is 2.8x worse (§Perf
+    # iteration 2).  An MLA cache holds the latent in the model dtype and
+    # takes "model" only (layers.init_mla_cache refuses "int8").
     kv_cache_dtype: str = "int8"
 
     def __post_init__(self):

@@ -2,9 +2,10 @@
 
 Covers every assigned architecture family:
   * GQA attention (+ optional qk-norm), RoPE
-  * MLA (DeepSeek-V2 compressed-KV attention)
+  * MLA (DeepSeek-V2 compressed-KV attention, YaRN, latent-space decode)
   * SwiGLU / GELU MLPs
-  * MoE with shared experts + top-k routing (dense dispatch; EP-shardable)
+  * MoE with shared experts + top-k routing (masked dense dispatch, or
+    dropless grouped dispatch)
   * Mamba selective-SSM block (associative-scan train/prefill, stateful decode)
   * mLSTM / sLSTM blocks (xLSTM)
   * optional mHC hyper-connection residual streams (paper RQ3 feature)
@@ -65,10 +66,43 @@ def apply_norm(p, x, cfg: ArchConfig, eps=1e-6):
 # RoPE
 # --------------------------------------------------------------------------
 
-def rope_freqs(dim: int, theta: float, positions):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor, as DeepSeek-V2 publishes it."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_range(dim: int, theta: float, s) -> Tuple[int, int]:
+    """The rotary pair indices (low, high) between which YaRN's ramp runs:
+    pairs below ``low`` turn more than ``beta_fast`` times over the
+    original context and keep their frequency, pairs above ``high`` turn
+    fewer than ``beta_slow`` times and are interpolated by ``factor``."""
+    def at(turns):
+        return dim * math.log(s.original_max_position_embeddings
+                              / (turns * 2 * math.pi)) / (2 * math.log(theta))
+    return (max(math.floor(at(s.beta_fast)), 0),
+            min(math.ceil(at(s.beta_slow)), dim - 1))
+
+
+def rope_inv_freq(dim: int, theta: float, scaling=None):
+    """The dim/2 rotary frequencies, YaRN-blended when ``scaling`` is a
+    :class:`~repro.models.config.YaRN`."""
     inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if scaling is None:
+        return inv
+    low, high = yarn_range(dim, theta, scaling)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
+
+
+def rope_freqs(dim: int, theta: float, positions, scaling=None):
+    inv = rope_inv_freq(dim, theta, scaling)
     ang = positions.astype(jnp.float32)[..., None] * inv  # (..., dim/2)
-    return jnp.cos(ang), jnp.sin(ang)
+    if scaling is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    m = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+        scaling.factor, scaling.mscale_all_dim)
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def apply_rope(x, cos, sin):
@@ -84,6 +118,16 @@ def apply_rope(x, cos, sin):
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     return jnp.concatenate([xf1 * cos - xf2 * sin,
                             xf2 * cos + xf1 * sin], -1).astype(x.dtype)
+
+
+def apply_rope_interleaved(x, cos, sin):
+    """RoPE on rotary pairs laid out interleaved, (x0, x1), (x2, x3), ...,
+    as DeepSeek-V2's published weights lay them out: the pairs are gathered
+    into halves and rotated as :func:`apply_rope` does, so the result is in
+    the split-halves layout (for queries and keys alike)."""
+    *lead, d = x.shape
+    x = x.reshape(*lead, d // 2, 2).swapaxes(-1, -2).reshape(*lead, d)
+    return apply_rope(x, cos, sin)
 
 
 # --------------------------------------------------------------------------
@@ -214,10 +258,43 @@ def init_mla(key, cfg: ArchConfig):
     }
 
 
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """1/sqrt(q/k head dim), times ``mscale(factor, mscale_all_dim)**2``
+    under YaRN."""
+    scale = 1.0 / math.sqrt(cfg.nope_head_dim + cfg.rope_head_dim)
+    s = cfg.rope_scaling
+    if s is not None and s.mscale_all_dim:
+        scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(cfg: ArchConfig, positions):
+    return rope_freqs(cfg.rope_head_dim, cfg.rope_theta, positions,
+                      cfg.rope_scaling)
+
+
+def mla_latent(p, x, cfg: ArchConfig, positions):
+    """What MLA caches for x (B, S, d): the normed latent c_kv
+    (B, S, kv_lora) and the rotated key shared by every head, k_pe
+    (B, S, rope_head_dim)."""
+    kv_a = x @ p["wkv_a"]                           # (B, S, kv_lora + dr)
+    c_kv = _qk_norm(kv_a[..., :cfg.kv_lora], p["kv_norm"])
+    cos, sin = _mla_rope(cfg, positions)
+    k_pe = apply_rope_interleaved(kv_a[..., None, cfg.kv_lora:], cos, sin)
+    return c_kv, k_pe[..., 0, :]
+
+
 def apply_mla(p, x, cfg: ArchConfig, *, positions=None, cache=None):
     """MLA attention.  cache (decode): compressed c_kv + k_pe per position —
     the memory win that motivates MLA (cache is (kv_lora + rope_dim) wide
-    instead of 2 * n_kv * head_dim)."""
+    instead of 2 * n_kv * head_dim).
+
+    Prefill up-projects the latent to per-head keys and values and runs
+    the framework's attention entry (the generated flash chain on a TPU),
+    with v zero-padded to the q/k head dim and sliced after.  Decode
+    attends in the latent space, with ``wkv_b`` absorbed into the query
+    and the output (the same mathematics): it reads only the cache's
+    kv_lora + rope_dim values per position."""
     B, S, d = x.shape
     nh = cfg.n_heads
     dn, dr, dv = cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
@@ -225,62 +302,65 @@ def apply_mla(p, x, cfg: ArchConfig, *, positions=None, cache=None):
         positions = jnp.arange(S)
 
     q = (x @ p["wq"]).reshape(B, S, nh, dn + dr)
-    q_nope, q_pe = q[..., :dn], q[..., dn:]
-    cos, sin = rope_freqs(dr, cfg.rope_theta, positions)
-    q_pe = apply_rope(q_pe, cos, sin)
+    cos, sin = _mla_rope(cfg, positions)
+    q_nope, q_pe = q[..., :dn], apply_rope_interleaved(q[..., dn:], cos, sin)
+    c_kv, k_pe = mla_latent(p, x, cfg, positions)
+    w_kv = p["wkv_b"].reshape(cfg.kv_lora, nh, dn + dv)
+    scale = mla_softmax_scale(cfg)
 
-    kv_a = x @ p["wkv_a"]                           # (B, S, kv_lora + dr)
-    c_kv, k_pe = kv_a[..., :cfg.kv_lora], kv_a[..., cfg.kv_lora:]
-    c_kv = (c_kv.astype(jnp.float32)
-            * jax.lax.rsqrt((c_kv.astype(jnp.float32) ** 2)
-                            .mean(-1, keepdims=True) + 1e-6)
-            * p["kv_norm"]).astype(x.dtype)
-    k_pe = apply_rope(k_pe[:, :, None, :], cos, sin)   # (B, S, 1, dr)
-
-    if cache is not None:
-        idx = cache["length"]
-        c_cache = jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(
-            c, u, (i, 0)))(cache["c_kv"], c_kv, idx)
-        pe_cache = jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(
-            c, u, (i, 0)))(cache["k_pe"], k_pe[:, :, 0, :], idx)
-        c_kv_full, k_pe_full = c_cache, pe_cache[:, :, None, :]
-        kv_len = cache["c_kv"].shape[1]      # static cache capacity
-        mask_len = idx + 1
-        new_cache = {"c_kv": c_cache, "k_pe": pe_cache, "length": idx + 1}
-    else:
-        c_kv_full, k_pe_full = c_kv, k_pe
-        kv_len = S
-        mask_len = None
-        new_cache = None
-
-    kv = (c_kv_full @ p["wkv_b"]).reshape(B, kv_len, nh, dn + dv)
-    k_nope, v = kv[..., :dn], kv[..., dn:]
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_pe_full, (B, kv_len, nh, dr))], -1)
-    qh = jnp.concatenate([q_nope, q_pe], -1)
-
-    sm_scale = 1.0 / math.sqrt(dn + dr)
     if cache is None:
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qh.astype(jnp.float32),
-                            k.astype(jnp.float32)) * sm_scale
-        qi = jnp.arange(S)[:, None]
-        ki = jnp.arange(kv_len)[None, :]
-        logits = jnp.where((qi >= ki)[None, None], logits, -jnp.inf)
-        prob = jax.nn.softmax(logits, -1)
-        out = jnp.einsum("bhqk,bkhd->bqhd", prob, v.astype(jnp.float32))
+        kv = jnp.einsum("bsc,chd->bshd", c_kv, w_kv)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_pe[:, :, None, :],
+                                            (B, S, nh, dr))], -1)
+        D = max(dn + dr, dv)
+
+        def pad(a):
+            return jnp.pad(a, ((0, 0),) * 3 + ((0, D - a.shape[-1]),))
+        out = fa_ops.attention(pad(jnp.concatenate([q_nope, q_pe], -1)),
+                               pad(k), pad(kv[..., dn:]), causal=cfg.causal,
+                               sm_scale=scale, impl=cfg.attn_impl)[..., :dv]
+        new_cache = None
     else:
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qh.astype(jnp.float32),
-                            k.astype(jnp.float32)) * sm_scale
-        ki = jnp.arange(kv_len)[None, None, None, :]
-        logits = jnp.where(ki < mask_len[:, None, None, None], logits,
-                           -jnp.inf)
-        prob = jax.nn.softmax(logits, -1)
-        out = jnp.einsum("bhqk,bkhd->bqhd", prob, v.astype(jnp.float32))
-    out = out.astype(x.dtype).reshape(B, -1, nh * dv)
+        idx = cache["length"]
+
+        def write(c, u):
+            return jax.vmap(lambda c_, u_, i_: jax.lax.dynamic_update_slice(
+                c_, u_.astype(c_.dtype), (i_, 0)))(c, u, idx)
+        c_all, pe_all = write(cache["c_kv"], c_kv), write(cache["k_pe"], k_pe)
+        new_cache = {"c_kv": c_all, "k_pe": pe_all, "length": idx + 1}
+        with jax.named_scope("latent"):
+            out = _mla_latent_attention(q_nope, q_pe, c_all, pe_all, idx + 1,
+                                        w_kv, dn, scale)
+    out = out.astype(x.dtype).reshape(B, S, nh * dv)
     return out @ p["wo"], new_cache
 
 
+def _mla_latent_attention(q_nope, q_pe, c_kv, k_pe, length, w_kv, dn,
+                          scale):
+    """Attention over a latent cache (B, T, kv_lora) + (B, T, dr) for
+    queries (B, S, H, .), positions >= ``length`` (B,) masked: scores
+    (q_nope W_UK^T) . c_kv + q_pe . k_pe, values (p . c_kv) W_UV."""
+    f32 = jnp.float32
+    q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w_kv[..., :dn])
+    s = (jnp.einsum("bshc,btc->bhst", q_lat.astype(c_kv.dtype), c_kv,
+                    preferred_element_type=f32)
+         + jnp.einsum("bshr,btr->bhst", q_pe.astype(k_pe.dtype), k_pe,
+                      preferred_element_type=f32)) * scale
+    live = jnp.arange(c_kv.shape[1])[None, None, None, :] \
+        < length[:, None, None, None]
+    prob = jax.nn.softmax(jnp.where(live, s, -jnp.inf), -1)
+    o_lat = jnp.einsum("bhst,btc->bhsc", prob.astype(c_kv.dtype), c_kv,
+                       preferred_element_type=f32)
+    return jnp.einsum("bhsc,chd->bshd", o_lat.astype(w_kv.dtype),
+                      w_kv[..., dn:])
+
+
 def init_mla_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=None):
+    if cfg.kv_cache_dtype != "model":
+        raise ValueError(f"{cfg.name}: an MLA cache holds the latent in the "
+                         f"model dtype; kv_cache_dtype="
+                         f"{cfg.kv_cache_dtype!r} is not supported")
     dt = jnp.dtype(dtype or cfg.dtype)
     return {
         "c_kv": jnp.zeros((batch, max_len, cfg.kv_lora), dt),
@@ -343,110 +423,81 @@ def init_moe(key, cfg: ArchConfig):
     return p
 
 
+def route(p, x, cfg: ArchConfig):
+    """Gates (T, top_k) float32 and expert ids (T, top_k) for tokens x
+    (T, d): the router's float32 logits, softmax over every expert, the
+    greedy top_k of it, renormalised when ``cfg.norm_topk_prob``."""
+    logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), cfg.top_k)
+    if cfg.norm_topk_prob:
+        gates = gates / gates.sum(-1, keepdims=True)
+    return gates, idx
+
+
 def apply_moe(p, x, cfg: ArchConfig):
-    if getattr(cfg, "moe_impl", "capacity") == "dense":
-        return apply_moe_dense(p, x, cfg)
-    return apply_moe_capacity(p, x, cfg)
-
-
-def apply_moe_dense(p, x, cfg: ArchConfig):
-    """Dense dispatch MoE: every expert processes every token, masked by the
-    routing weights.  Simple and collective-free but O(E) FLOPs — kept as
-    the reference implementation (§Perf iteration 3 replaced it with
-    capacity dispatch as the default)."""
+    """Routed experts (``cfg.moe_impl``) plus the shared experts, each
+    under its own named scope."""
     B, S, d = x.shape
-    E, k = cfg.n_experts, cfg.top_k
-    logits = (x.astype(jnp.float32) @ p["router"])           # (B, S, E)
-    topv, topi = jax.lax.top_k(logits, k)
-    gates = jax.nn.softmax(topv, axis=-1)                     # (B, S, k)
-    # combine into per-expert weights (B, S, E), zero off the top-k
-    w = jnp.zeros_like(logits).at[
-        jnp.arange(B)[:, None, None], jnp.arange(S)[None, :, None], topi
-    ].set(gates)
+    xf = x.reshape(B * S, d)
+    with jax.named_scope("router"):
+        gates, idx = route(p, xf, cfg)
+    with jax.named_scope("experts"):
+        experts = {"dense": _experts_dense,
+                   "grouped": _experts_grouped}[cfg.moe_impl]
+        y = experts(p["experts"], xf, gates, idx).reshape(B, S, d)
+    if "shared" in p:
+        with jax.named_scope("shared_experts"):
+            y = y + apply_mlp(p["shared"], x, "swiglu")
+    return y.astype(x.dtype)
+
+
+def _expert_groups(ex):
+    """The expert matrices as groups (G, ., .), and where this layer's E
+    experts start among them.  ``ex`` holds one layer's experts
+    (E, ., .), or, with the layer's index under ``layer``, every layer's
+    stacked (R, E, ., .), which the unrolled serving loop passes so that
+    no layer's experts are copied out of the stack."""
+    ws = [ex[k] for k in ("w_gate", "w_up", "w_down")]
+    E = ws[0].shape[-3]
+    return [w.reshape(-1, *w.shape[-2:]) for w in ws], \
+        ex.get("layer", 0) * E, E
+
+
+def _experts_dense(ex, x, gates, idx):
+    """Masked dense dispatch: every expert processes every token, weighted
+    by its gate, zero off the token's top_k.  Simple and collective-free
+    but O(E) FLOPs."""
+    ws, first, E = _expert_groups(ex)
+    w = jnp.einsum("tke,tk->te", jax.nn.one_hot(idx, E, dtype=gates.dtype),
+                   gates)
 
     def one_expert(wg, wu, wd):
-        h = jax.nn.silu(x @ wg) * (x @ wu)
-        return h @ wd                                          # (B, S, d)
+        return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd            # (T, d)
 
-    y = jnp.einsum(
-        "ebsd,bse->bsd",
-        jax.vmap(one_expert)(p["experts"]["w_gate"], p["experts"]["w_up"],
-                             p["experts"]["w_down"]),
-        w.astype(x.dtype))
-    if "shared" in p:
-        y = y + apply_mlp(p["shared"], x, "swiglu")
-    return y.astype(x.dtype)
+    return jnp.einsum("etd,te->td", jax.vmap(one_expert)(
+        *[w[first:first + E] for w in ws]), w.astype(x.dtype))
 
 
-def _maybe_constrain(x, spec_axes):
-    """with_sharding_constraint when a mesh context is active; no-op when
-    running meshless (unit tests, single device)."""
-    try:
-        from jax.sharding import PartitionSpec as P
-        return jax.lax.with_sharding_constraint(x, P(*spec_axes))
-    except Exception:  # noqa: BLE001 — no mesh / missing axis
-        return x
+def _experts_grouped(ex, x, gates, idx):
+    """Dropless grouped dispatch: the T * top_k (token, expert) pairs sorted
+    by expert, each expert's SwiGLU run over its rows as grouped matrix
+    products, and each token's top_k outputs summed by its gates.  Every
+    pair is computed, whatever the batch."""
+    T, k = idx.shape
+    (w_gate, w_up, w_down), first, _ = _expert_groups(ex)
+    flat = idx.reshape(-1) + first
+    order = jnp.argsort(flat)                   # stable: token order kept
+    sizes = jnp.bincount(flat, length=w_gate.shape[0]).astype(jnp.int32)
+    rows = x[order // k]                                        # (T*k, d)
 
+    def grouped(a, w):
+        return jax.lax.ragged_dot(a, w, sizes,
+                                  preferred_element_type=jnp.float32)
 
-def apply_moe_capacity(p, x, cfg: ArchConfig,
-                       capacity_factor: float = 1.25):
-    """Capacity-bucketed sparse dispatch (SPerf iteration 3): tokens are
-    sorted by expert assignment and scattered into (E, C, d) buckets; each
-    expert runs dense matmuls on its bucket only.  FLOPs drop from O(E) to
-    O(top_k * capacity_factor) per token (~6.4x for 16e top-2).  With
-    experts sharded over `model`, the scatter/gather pair is the
-    all-to-all dispatch of standard EP.  Overflow beyond the static
-    capacity is dropped (switch-style routing)."""
-    B, S, d = x.shape
-    E, k = cfg.n_experts, cfg.top_k
-    T = B * S
-    xf = x.reshape(T, d)
-    logits = (xf.astype(jnp.float32) @ p["router"])           # (T, E)
-    topv, topi = jax.lax.top_k(logits, k)
-    gates = jax.nn.softmax(topv, axis=-1).astype(x.dtype)     # (T, k)
-
-    if T <= 512:
-        # decode / tiny batches: full capacity (no drops) — the buckets are
-        # small, and decode must be exact w.r.t. the teacher-forced path
-        C = T
-    else:
-        C = max(1, int(T * k * capacity_factor) // E)
-    expert_idx = topi.reshape(-1)                             # (T*k,)
-    token_idx = jnp.repeat(jnp.arange(T), k)
-    gate_flat = gates.reshape(-1)
-
-    order = jnp.argsort(expert_idx)                           # stable
-    se = expert_idx[order]
-    stok = token_idx[order]
-    sgate = gate_flat[order]
-    counts = jnp.bincount(expert_idx, length=E)
-    starts = jnp.concatenate([jnp.zeros((1,), counts.dtype),
-                              jnp.cumsum(counts)[:-1]])
-    pos = jnp.arange(T * k) - starts[se]                      # slot in expert
-    keep = pos < C
-    pos_c = jnp.where(keep, pos, 0)
-
-    buckets = jnp.zeros((E, C, d), x.dtype)
-    buckets = buckets.at[se, pos_c].add(
-        jnp.where(keep[:, None], xf[stok], 0).astype(x.dtype))
-    # EP: pin the bucket/expert axis to the model mesh axis — without this
-    # GSPMD replicates the expert einsums on every device (§Perf M2)
-    buckets = _maybe_constrain(buckets, ("model", None, None))
-
-    ex = p["experts"]
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buckets, ex["w_gate"])) \
-        * jnp.einsum("ecd,edf->ecf", buckets, ex["w_up"])
-    h = _maybe_constrain(h, ("model", None, None))
-    out = jnp.einsum("ecf,efd->ecd", h, ex["w_down"])         # (E, C, d)
-    out = _maybe_constrain(out, ("model", None, None))
-
-    y = jnp.zeros((T, d), x.dtype).at[stok].add(
-        jnp.where(keep[:, None], out[se, pos_c]
-                  * sgate[:, None].astype(x.dtype), 0))
-    y = y.reshape(B, S, d)
-    if "shared" in p:
-        y = y + apply_mlp(p["shared"], x, "swiglu")
-    return y.astype(x.dtype)
+    h = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+    out = grouped(h.astype(x.dtype), w_down)
+    out = out[jnp.argsort(order)].reshape(T, k, -1)
+    return jnp.einsum("tkd,tk->td", out, gates)
 
 
 # --------------------------------------------------------------------------

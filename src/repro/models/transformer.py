@@ -323,7 +323,21 @@ def init_caches(cfg: ArchConfig, batch: int, max_len: int):
 
 
 def _unrolled_layer_params(params, cfg: ArchConfig, rep: int):
-    return {f"l{i}": jax.tree.map(lambda a: a[rep], params["body"][f"l{i}"])
+    """Layer ``rep`` of each pattern slot of the layer-stacked body.  An
+    MoE layer's expert weights stay stacked, with the layer's index under
+    ``experts/layer`` (``layers.apply_moe`` reads its own layer's experts
+    there): a slice of them would be copied out of the stack, a whole
+    layer's experts per layer and step."""
+    def take(p):
+        ffn = p.get("ffn")
+        experts = ffn.get("experts") if isinstance(ffn, dict) else None
+        if experts is not None:
+            p = {**p, "ffn": {k: v for k, v in ffn.items() if k != "experts"}}
+        p = jax.tree.map(lambda a: a[rep], p)
+        if experts is not None:
+            p["ffn"]["experts"] = dict(experts, layer=rep)
+        return p
+    return {f"l{i}": take(params["body"][f"l{i}"])
             for i in range(len(cfg.pattern))}
 
 
@@ -470,19 +484,12 @@ def _prefill_forward(params, cfg, batch, caches):
 def _fill_attn_cache(p, h, cfg: ArchConfig, cache, positions):
     B, S = h.shape[:2]
     if cfg.mla:
-        kv_a = h @ p["wkv_a"]
-        c_kv, k_pe = kv_a[..., :cfg.kv_lora], kv_a[..., cfg.kv_lora:]
-        c_kv = (c_kv.astype(jnp.float32)
-                * jax.lax.rsqrt((c_kv.astype(jnp.float32) ** 2)
-                                .mean(-1, keepdims=True) + 1e-6)
-                * p["kv_norm"]).astype(h.dtype)
-        cos, sin = L.rope_freqs(cfg.rope_head_dim, cfg.rope_theta, positions)
-        k_pe = L.apply_rope(k_pe[:, :, None, :], cos, sin)[:, :, 0, :]
+        c_kv, k_pe = L.mla_latent(p, h, cfg, positions)
         new = dict(cache)
         new["c_kv"] = jax.lax.dynamic_update_slice(
-            cache["c_kv"], c_kv, (0, 0, 0))
+            cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), (0, 0, 0))
         new["k_pe"] = jax.lax.dynamic_update_slice(
-            cache["k_pe"], k_pe, (0, 0, 0))
+            cache["k_pe"], k_pe.astype(cache["k_pe"].dtype), (0, 0, 0))
         new["length"] = jnp.full_like(cache["length"], S)
         return new
     hd = cfg.resolved_head_dim

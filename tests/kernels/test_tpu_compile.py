@@ -108,3 +108,34 @@ def test_flat_task_compiles_at_check_shape(name, one_chip,
 def test_flash_chain_compiles_streaming(one_chip, no_persistent_cache):
     assert "tpu_custom_call" in _compile_chain("flash_attention",
                                                _flash_shapes(8192), one_chip)
+
+
+@pytest.mark.parametrize("s", [512, 3072])
+def test_flash_chain_compiles_resident_at_mla_head_dim(s, one_chip,
+                                                       no_persistent_cache):
+    """DeepSeek-V2's q/k head dim 192 (v zero-padded to it): resident up
+    to 3072 keys; at 4096 the chain streams and attention runs the
+    reference (tests/models/test_mla.py)."""
+    assert "tpu_custom_call" in _compile_chain(
+        "flash_attention", _flash_shapes(s, d=192), one_chip)
+
+
+def test_grouped_experts_read_the_layer_stack_in_place(one_chip,
+                                                       no_persistent_cache):
+    """DeepSeek-V2-Lite's decode MoE at published widths: the grouped
+    products read one layer's 64 experts out of the (8, 64, ...) stack
+    without copying them (1.06 GB a layer) into a temporary."""
+    import repro.models.layers as L
+    bf16 = jnp.bfloat16
+
+    def shape(s, dt=bf16):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    ex = {"w_gate": shape((8, 64, 2048, 1408)),
+          "w_up": shape((8, 64, 2048, 1408)),
+          "w_down": shape((8, 64, 1408, 2048))}
+    fn = jax.jit(lambda e, x, g, i: L._experts_grouped(dict(e, layer=3), x,
+                                                       g, i))
+    compiled = fn.lower(ex, shape((16, 2048)), shape((16, 6), jnp.float32),
+                        shape((16, 6), jnp.int32)).compile()
+    assert "ragged" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
